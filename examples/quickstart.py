@@ -11,6 +11,8 @@
 
 import os
 
+# a simulated 8-device host mesh: stay on the CPU even where a chip exists
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
@@ -65,15 +67,7 @@ def main():
     zoo = get_model(cfg_m)
     data = SyntheticLM(DataConfig(vocab=cfg_m.vocab, seq_len=32, global_batch=8))
     ocfg = opt_lib.AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=20)
-    # jax 0.4.x aborts in XLA on the partial-manual shard_map the explicit
-    # hierarchical schedule uses (see tests/test_distributed.py xfail);
-    # fall back to the GSPMD trainer there — numerics are identical.
-    if hasattr(jax.sharding, "AxisType"):
-        dp_mode, schedule = "manual_hier", "hierarchical"
-    else:
-        dp_mode, schedule = "gspmd_fsdp", "n/a"
-        print("\n(jax 0.4.x detected: using the GSPMD trainer; the explicit "
-              "hierarchical schedule needs jax >= 0.5)")
+    dp_mode, schedule = "manual_hier", "hierarchical"
     arts = make_train_step(zoo, ocfg, mesh, data.batch(0),
                            dp_mode=dp_mode, schedule=schedule)
     p = jax.device_put(zoo.init(jax.random.PRNGKey(0)), arts.param_sharding)

@@ -6,6 +6,8 @@ slot scheduler, on a (data, model) mesh with sharded KV caches.
 
 import os
 
+# a simulated 8-device host mesh: stay on the CPU even where a chip exists
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax

@@ -21,14 +21,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..compat import degraded_partial_auto
-from .schedules import (
-    AxisNames,
-    _axes_tuple,
-    all_gather_axis,
-    all_reduce_axis,
-    reduce_scatter_axis,
-)
+from .schedules import AxisNames, all_gather_axis, reduce_scatter_axis
 
 
 class Int8Compressed(NamedTuple):
@@ -91,21 +84,8 @@ def compressed_hierarchical_all_reduce(
     shard, which is why this targets the small slow axis (pod).
     The payload appears as an ``s8`` all-gather in compiled HLO — the
     roofline collective parser credits the savings automatically.
-
-    Inside a partial-auto shard_map on jax 0.4.x the scatter/gather
-    phases cannot be lowered (XLA aborts the process — see
-    ``repro.compat``); the schedule then degrades to int8-compressing the
-    *local* gradient and psum-reducing the dequantized values — the same
-    quantization noise model without the byte savings.
     """
     orig_dtype = x.dtype
-    if degraded_partial_auto():
-        comp = int8_compress(x, chunk)
-        approx = int8_decompress(comp, x.shape, jnp.float32)
-        out = all_reduce_axis(approx, intra_axes)
-        if _axes_tuple(inter_axes):
-            out = all_reduce_axis(out, inter_axes)
-        return out.astype(orig_dtype)
     shard = reduce_scatter_axis(x, intra_axes, dim=0)
     comp = int8_compress(shard, chunk)
     vals = all_gather_axis(comp.values[None], inter_axes, dim=0)   # (p, C, chunk) int8

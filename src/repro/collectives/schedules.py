@@ -29,8 +29,6 @@ import functools
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
-
-from ..compat import axis_size, degraded_partial_auto, shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -44,7 +42,7 @@ def _axes_tuple(axes: AxisNames) -> Tuple[str, ...]:
 def _axis_size(axes: AxisNames) -> int:
     size = 1
     for a in _axes_tuple(axes):
-        size *= axis_size(a)
+        size *= jax.lax.axis_size(a)
     return size
 
 
@@ -101,18 +99,7 @@ def hierarchical_all_reduce(
 
     Requires ``x.shape[scatter_dim]`` divisible by the intra axes' total
     size.  Phase 2's inter-node traffic is V/|intra| per chip.
-
-    Inside a partial-auto shard_map on jax 0.4.x the scatter/gather
-    phases cannot be lowered (XLA aborts the process — see
-    ``repro.compat``); the schedule then degrades to sequential psums
-    over the two axis groups, which computes the identical sum without
-    the inter-phase byte reduction.
     """
-    if degraded_partial_auto():
-        x = all_reduce_axis(x, intra_axes)
-        if _axes_tuple(inter_axes):
-            x = all_reduce_axis(x, inter_axes)
-        return x
     x = reduce_scatter_axis(x, intra_axes, dim=scatter_dim)   # k x BW domain
     x = all_reduce_axis(x, inter_axes)                        # rails
     x = all_gather_axis(x, intra_axes, dim=scatter_dim)       # k x BW domain
@@ -128,7 +115,7 @@ def ring_all_reduce_2d(
     reduce-scattered along X then Y, half B along Y then X; then the
     mirrored all-gathers.  Models the X/Y simultaneous rings of [48, 98]."""
     ax, ay = axes_xy
-    group = 2 * axis_size(ax) * axis_size(ay)
+    group = 2 * jax.lax.axis_size(ax) * jax.lax.axis_size(ay)
     x, pad = _pad_to_multiple(x, group, scatter_dim)
     n = x.shape[scatter_dim]
     half = n // 2
@@ -189,9 +176,7 @@ def tree_hierarchical_all_reduce(
 ):
     """Apply the hierarchical schedule leaf-wise (flattening each leaf so
     the scatter dim is always divisible; pads then unpads)."""
-    intra = 1
-    for a in _axes_tuple(intra_axes):
-        intra *= axis_size(a)
+    intra = _axis_size(intra_axes)
 
     def red(g):
         shape = g.shape
@@ -235,7 +220,7 @@ def make_all_reduce_fn(
             return ring_all_reduce_2d(x, (ax[0], ax[1]))
         raise ValueError(schedule)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
     )
     return jax.jit(mapped)

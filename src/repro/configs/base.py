@@ -60,7 +60,7 @@ class ModelConfig:
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.float32
     remat: bool = False
-    attn_impl: str = "ref"                  # ref | pallas
+    attn_impl: str = "ref"                  # ref | flash | flash_stub
 
     @property
     def resolved_head_dim(self) -> int:

@@ -26,22 +26,33 @@ class DataConfig:
     bigram_temp: float = 1.2
 
 
+_TABLE_ROWS = 1024
+
+
 def _bigram_table(cfg: DataConfig) -> np.ndarray:
+    """Row-stochastic (vocab, vocab) transition table, built _TABLE_ROWS
+    rows at a time so that temporaries stay small at a real vocabulary
+    (19k ids is 2.9 GB per table).  Rows are drawn in order, which is the
+    same random stream as one (vocab, vocab) draw."""
+    V = cfg.vocab
     rng = np.random.RandomState(cfg.seed)
-    logits = rng.randn(cfg.vocab, cfg.vocab) * cfg.bigram_temp
-    # sparsify: each token strongly prefers ~8 successors
-    top = np.argsort(-logits, axis=1)[:, :8]
-    boost = np.zeros_like(logits)
-    np.put_along_axis(boost, top, 4.0, axis=1)
-    p = np.exp(logits * 0.1 + boost)
-    return p / p.sum(axis=1, keepdims=True)
+    table = np.empty((V, V))
+    for lo in range(0, V, _TABLE_ROWS):
+        logits = rng.randn(min(_TABLE_ROWS, V - lo), V) * cfg.bigram_temp
+        # sparsify: each token strongly prefers ~8 successors
+        top = np.argpartition(-logits, min(7, V - 1), axis=1)[:, :8]
+        boost = np.zeros_like(logits)
+        np.put_along_axis(boost, top, 4.0, axis=1)
+        p = np.exp(logits * 0.1 + boost)
+        table[lo : lo + len(p)] = p / p.sum(axis=1, keepdims=True)
+    return table
 
 
 class SyntheticLM:
     def __init__(self, cfg: DataConfig):
         self.cfg = cfg
-        self.table = _bigram_table(cfg)
-        self.cum = np.cumsum(self.table, axis=1)
+        self.cum = _bigram_table(cfg)
+        np.cumsum(self.cum, axis=1, out=self.cum)
 
     def batch(self, step: int, shard: int = 0, num_shards: int = 1) -> Dict[str, np.ndarray]:
         """Deterministic batch for (step, shard): tokens + next-token targets."""

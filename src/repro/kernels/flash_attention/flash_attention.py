@@ -1,4 +1,4 @@
-"""Blockwise flash attention (forward) as a Pallas TPU kernel.
+"""Blockwise flash attention (forward and backward) as Pallas TPU kernels.
 
 Tiling: grid (B, H, nq, nk) — the k-block axis is innermost, so the TPU
 sequential grid revisits the same output block while streaming k/v tiles
@@ -10,10 +10,18 @@ kv-tiles: MXU-aligned (multiples of 128 on the matmul dims) and a VMEM
 working set of ~(2*bq*Dh + 2*bk*Dh + bq*bk) * 4 B ~ 0.5 MB at Dh=128 —
 comfortably inside the ~16 MB/core VMEM budget with double buffering.
 
-Causal + sliding-window masking is applied inside the tile; fully-masked
-k-tiles are skipped via the index check in ``pl.when`` (the grid itself is
-not pruned — acceptable for validation; on hardware one would carve the
-grid per q row for the ~2x causal win, noted in EXPERIMENTS §Perf).
+Causal + sliding-window masking is applied inside the tile.  The grid is
+not pruned: fully-masked k-tiles are still visited and their DMAs issued
+(carving the grid per q row would recover the ~2x causal saving).
+
+The per-row softmax statistics (``lse`` from the forward, ``delta`` in the
+backward) are carried as ``(B, H, Sq, 1)`` arrays: the TPU lowering needs
+the last two block dims to be multiples of (8, 128) or equal to the full
+array dims, so a ``(block_q, 1)`` block is legal where ``(1, 1, block_q)``
+over ``(B, H, Sq)`` is not.
+
+Every entry point takes ``interpret`` explicitly: ``True`` runs the Pallas
+interpreter (CPU), ``False`` compiles for the TPU.
 """
 
 from __future__ import annotations
@@ -115,7 +123,7 @@ def _flash_fwd_lse_kernel(
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log(l)).astype(lse_ref.dtype)
+        lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, None].astype(lse_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(
@@ -136,8 +144,8 @@ def _flash_bwd_dq_kernel(
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)
-    delta = delta_ref[0, 0].astype(jnp.float32)
+    lse = lse_ref[0, 0].astype(jnp.float32)            # (bq, 1)
+    delta = delta_ref[0, 0].astype(jnp.float32)        # (bq, 1)
     qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + q_offset
     kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     mask = jnp.ones((block_q, block_k), bool)
@@ -146,9 +154,9 @@ def _flash_bwd_dq_kernel(
     if window is not None:
         mask &= kpos > qpos - window
     s = jnp.where(mask, (q * scale) @ k.T, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                      # softmax probs
+    p = jnp.exp(s - lse)                               # softmax probs
     dp = do @ v.T                                      # (bq, bk)
-    ds = p * (dp - delta[:, None])                     # (bq, bk)
+    ds = p * (dp - delta)                              # (bq, bk)
     dq_scr[...] += (ds @ k) * scale
 
     @pl.when(ik == num_k_blocks - 1)
@@ -185,10 +193,10 @@ def _flash_bwd_dkv_kernel(
     if window is not None:
         mask &= kpos > qpos - window
     s = jnp.where(mask, (q * scale) @ k.T, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)
     dv_scr[...] += p.T @ do
     dp = do @ v.T
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     dk_scr[...] += (ds.T @ q) * scale
 
     @pl.when(iqb == num_q_blocks - 1)
@@ -199,7 +207,7 @@ def _flash_bwd_dkv_kernel(
 
 def flash_attention_fwd_lse(
     q, k, v, *, causal=True, window=None, scale=None, q_offset=0,
-    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, interpret=True,
+    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K, interpret: bool,
 ):
     B, H, Sq, Dh = q.shape
     Hk, Skv = k.shape[1], k.shape[2]
@@ -224,11 +232,11 @@ def flash_attention_fwd_lse(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
@@ -242,7 +250,7 @@ def flash_attention_fwd_lse(
 def flash_attention_bwd(
     q, k, v, o, lse, do, *, causal=True, window=None, scale=None,
     q_offset=0, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-    interpret=True,
+    interpret: bool,
 ):
     """Blocked backward (dq then dk/dv); GQA handled by summing dk/dv over
     the query-head group outside (kv heads are broadcast in the kernels)."""
@@ -255,8 +263,8 @@ def flash_attention_bwd(
     block_k = min(block_k, Skv)
     nq, nk = Sq // block_q, Skv // block_k
     delta = jnp.sum(
-        o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
-    )  # (B, H, Sq)
+        o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1, keepdims=True
+    )  # (B, H, Sq, 1)
 
     dq = pl.pallas_call(
         functools.partial(
@@ -269,8 +277,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
             pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
             pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
@@ -289,8 +297,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, ik, iq, g=group: (b, h // g, ik, 0)),
             pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, ik, iq, g=group: (b, h // g, ik, 0)),
             pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, ik, iq: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, ik, iq: (b, h, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, ik, iq: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, ik, iq: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, ik, iq: (b, h, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, ik, iq: (b, h, ik, 0)),
@@ -323,7 +331,7 @@ def flash_attention_fwd(
     q_offset: int = 0,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """q: (B, H, Sq, Dh); k/v: (B, Hk, Skv, Dh) with H % Hk == 0."""
     B, H, Sq, Dh = q.shape

@@ -1,10 +1,10 @@
 """jit'd public wrapper for the flash attention kernel.
 
 ``flash_attention`` takes model-layout tensors q (B, S, H, Dh),
-k/v (B, S, Hk, Dh), transposes to kernel layout, runs the Pallas kernel
-(interpret mode on CPU, compiled on TPU), and exposes a custom_vjp whose
-backward pass differentiates the reference oracle (numerically identical
-semantics; the bwd kernel is future work, noted in DESIGN.md).
+k/v (B, S, Hk, Dh), transposes to kernel layout and runs the Pallas
+kernels through a custom_vjp: the forward saves the logsumexp rows and the
+backward runs the blocked dq and dk/dv kernels.  The platform picks the
+mode: interpret on the CPU, compiled on the TPU.
 """
 
 from __future__ import annotations
@@ -20,7 +20,6 @@ from .flash_attention import (
     flash_attention_fwd,
     flash_attention_fwd_lse,
 )
-from .ref import attention_ref
 
 
 def _on_cpu() -> bool:

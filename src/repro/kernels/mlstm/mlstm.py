@@ -80,7 +80,7 @@ def mlstm_fwd(
     logf: jax.Array,   # (B, S, H)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, S, H, D = q.shape
     chunk = min(chunk, S)

@@ -73,7 +73,7 @@ def ssd_fwd(
     A: jax.Array,      # (H,)
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, S, H, P = x.shape
     N = Bm.shape[-1]

@@ -12,30 +12,23 @@ state (the dry-run must set XLA_FLAGS before first jax init).
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import jax
-
-
-def _axis_types_kwargs(num_axes: int) -> dict:
-    """``axis_types`` kwarg for jax.make_mesh on jax versions that have
-    AxisType (>= 0.5); older jax (e.g. 0.4.x) predates explicit axis types
-    and every axis behaves as Auto, so the kwarg is simply omitted."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * num_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
     """General mesh helper (tests / examples / heterogeneous topologies)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def railx_mesh_from_plan(plan) -> Tuple[Tuple[int, ...], Tuple[str, ...]]:

@@ -38,7 +38,6 @@ def main() -> None:
         )
 
     import jax
-    import numpy as np
 
     from ..configs import get_config, get_smoke_config
     from ..data.pipeline import DataConfig, SyntheticLM
@@ -46,7 +45,10 @@ def main() -> None:
     from ..train import optimizer as opt_lib
     from ..train.train_step import make_train_step
     from ..train.trainer import CheckpointPolicy, StragglerMonitor, train_loop, resume
+    from .compile_cache import enable_compile_cache
     from .mesh import make_mesh
+
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     zoo = get_model(cfg)
@@ -72,10 +74,7 @@ def main() -> None:
         schedule=args.schedule, microbatches=args.microbatches,
     )
     params = jax.device_put(zoo.init(jax.random.PRNGKey(0)), arts.param_sharding)
-    opt = jax.device_put(
-        opt_lib.init(ocfg, jax.tree_util.tree_map(np.asarray, params)),
-        arts.opt_sharding,
-    )
+    opt = jax.device_put(opt_lib.init(ocfg, params), arts.opt_sharding)
     start = 0
     ckpt = None
     if args.ckpt_dir:

@@ -23,8 +23,6 @@ from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-
-from ..compat import shard_map
 import jax.numpy as jnp
 
 from ..parallel.sharding import current_mesh, shard_hint
@@ -248,7 +246,7 @@ def moe_ffn_ep(
         aux = jax.lax.pmean(aux, batch_axes)
         return out.reshape(Bl, S, D), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -25,12 +25,11 @@ from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-
-from ..compat import shard_map
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
-from ..parallel.sharding import shard_hint
+from ..parallel.sharding import _manual_axes_in_context, current_mesh, shard_hint
 from . import common as C
 from .common import DTypes, Params
 from .moe import MoEConfig, init_moe, moe_ffn, moe_specs
@@ -247,39 +246,48 @@ def _stub_flash(q, k, v, causal, window, scale):
     return op(q, k, v)
 
 
-def _flash_sharded(q, k, v, mesh, causal, window, scale, stub=False):
-    """Flash attention as a shard_map island: batch over the DP axes, heads
-    over the TP axis, per-shard Pallas kernel — scores never materialize in
-    HBM.  ``stub=True`` lowers the per-shard kernel as an opaque custom-call
-    (dry-run: the CPU backend cannot compile TPU Pallas; the stub carries
-    identical operand/result traffic).
+def _flash_attention(q, k, v, causal, scale, stub=False):
+    """Flash attention in model layout, placed per shard so that scores
+    never materialize in HBM.
 
-    GQA KV heads are broadcast to the query heads first so the head dim
-    shards cleanly (the kernels reduce dk/dv back over the group)."""
-    from jax.sharding import PartitionSpec as P
+    * No mesh in context: the kernel is called directly.
+    * Under a mesh: a shard_map island with batch over the DP axes and
+      heads over the TP axis.  Inside a manual region (the ``manual_hier``
+      trainer) the arrays are already per-shard on the manual axes, so the
+      island spans only the remaining auto axes, and the kernel is called
+      directly when none remain.
 
+    GQA KV heads are broadcast to the query heads before an island so the
+    head dim shards cleanly (the kernels reduce dk/dv back over the group).
+    ``stub=True`` lowers the kernel as an opaque custom-call (dry run: the
+    CPU backend cannot compile TPU Pallas; the stub carries identical
+    operand/result traffic)."""
     from ..kernels.flash_attention.ops import flash_attention
 
+    def kernel(q, k, v):
+        if stub:
+            return _stub_flash(q, k, v, causal, None, scale)
+        return flash_attention(q, k, v, causal=causal, scale=scale)
+
+    mesh = current_mesh()
+    manual = _manual_axes_in_context() or set()
+    auto = [a for a in mesh.axis_names if a not in manual] if mesh is not None else []
+    if not auto:
+        return kernel(q, k, v)
     B, S, H, Dh = q.shape
-    Hk = k.shape[2]
-    group = H // Hk
+    group = H // k.shape[2]
     if group > 1:
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
-    dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
-    tp = "model" if "model" in mesh.shape else None
-    bspec = dp if (dp and B % max(1, math.prod(mesh.shape[a] for a in dp)) == 0) else None
+    dp = tuple(a for a in ("pod", "data") if a in auto)
+    tp = "model" if "model" in auto else None
+    bspec = dp if (dp and B % math.prod(mesh.shape[a] for a in dp) == 0) else None
     hspec = tp if (tp and H % mesh.shape[tp] == 0) else None
     spec = P(bspec, None, hspec, None)
-
-    def body(q, k, v):
-        if stub:
-            return _stub_flash(q, k, v, causal, window, scale)
-        return flash_attention(q, k, v, causal=causal, window=window, scale=scale)
-
-    return shard_map(
-        body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False,
+    return jax.shard_map(
+        kernel, mesh=jax.sharding.get_abstract_mesh() if manual else mesh,
+        in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=set(auto), check_vma=False,
     )(q, k, v)
 
 
@@ -306,19 +314,20 @@ def _attention_dynwin(
         q = C.apply_rope(q, positions, acfg.rope_theta)
         k = C.apply_rope(k, positions, acfg.rope_theta)
     scale = 1.0 / math.sqrt(Dh)
-    if impl in ("flash", "flash_stub") and acfg.window is None:
-        from ..parallel.sharding import _manual_axes_in_context
-        from ..parallel import sharding as _sh
-
-        mesh = getattr(_sh._state, "mesh", None)
-        if mesh is not None and _manual_axes_in_context() is None:
-            out = _flash_sharded(
-                q, k, v, mesh, acfg.causal, None, scale,
-                stub=(impl == "flash_stub"),
+    if impl in ("flash", "flash_stub"):
+        if acfg.window is not None:
+            # the layer's window is switched by a traced flag, which the
+            # kernel's static mask cannot follow
+            raise ValueError(
+                f"attn_impl={impl!r} has no path for sliding-window layers "
+                f"(window={acfg.window}); use attn_impl='ref'"
             )
-            out = out.reshape(B, S, H * Dh)
-            out = shard_hint(out, ("batch", "seq", "heads"))
-            return C.linear(p["wo"], out, dt)
+        out = _flash_attention(
+            q, k, v, acfg.causal, scale, stub=(impl == "flash_stub")
+        )
+        out = out.reshape(B, S, H * Dh)
+        out = shard_hint(out, ("batch", "seq", "heads"))
+        return C.linear(p["wo"], out, dt)
     group = H // Hk
     qf = q.astype(jnp.float32) * scale
     qg = qf.reshape(B, S, Hk, group, Dh)

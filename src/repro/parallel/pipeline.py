@@ -20,7 +20,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
-from ..compat import axis_size, shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -41,7 +40,7 @@ def pipeline_forward(
     Schedule: GPipe-style fill-drain over T = M + S - 1 ticks; activations
     ppermute one hop per tick.
     """
-    S = axis_size(axis)
+    S = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     M = micro_inputs.shape[0]
     T = M + S - 1
@@ -93,11 +92,11 @@ def make_pipelined_apply(
         outs = pipeline_forward(stage_fn, local_params, inputs, axis=axis)
         # broadcast final outputs from the last stage to all stages
         # (mask + psum: ppermute cannot express one-to-many)
-        last = axis_size(axis) - 1
+        last = jax.lax.axis_size(axis) - 1
         outs = jnp.where(jax.lax.axis_index(axis) == last, outs, 0)
         return jax.lax.psum(outs, axis)
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P()),

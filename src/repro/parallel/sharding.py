@@ -93,14 +93,7 @@ def current_rules() -> Optional[ShardingRules]:
 
 
 def current_mesh() -> Optional[Mesh]:
-    m = getattr(_state, "mesh", None)
-    if m is not None:
-        return m
-    try:
-        env = jax.sharding.get_abstract_mesh()  # type: ignore[attr-defined]
-    except Exception:
-        env = None
-    return None
+    return getattr(_state, "mesh", None)
 
 
 @contextlib.contextmanager
@@ -171,26 +164,13 @@ def make_rules(
 def _manual_axes_in_context() -> Optional[set]:
     """Axes marked Manual in the current abstract mesh (inside shard_map),
     or None when no abstract mesh / no manual axes."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        am = None
-    if am is not None and getattr(am, "axis_names", None):
-        manual = {
-            name
-            for name, t in zip(am.axis_names, am.axis_types)
-            if "Manual" in str(t)
-        }
-        return manual or None
-    # jax 0.4.x: no abstract mesh; manual axes are exactly the names bound
-    # in the trace-time axis env inside shard_map.
-    try:
-        import jax.core as jcore
-
-        names = jcore.unsafe_get_axis_names_DO_NOT_USE()
-    except Exception:
-        return None
-    return set(names) or None
+    am = jax.sharding.get_abstract_mesh()
+    manual = {
+        name
+        for name, t in zip(am.axis_names, am.axis_types)
+        if t == jax.sharding.AxisType.Manual
+    }
+    return manual or None
 
 
 def _project_spec(spec: P, drop: set) -> P:
@@ -218,11 +198,6 @@ def shard_hint(x: jax.Array, names: Sequence[Optional[str]]) -> jax.Array:
     spec = rules.spec(names)
     manual = _manual_axes_in_context()
     if manual is not None:
-        if not hasattr(jax.sharding, "get_abstract_mesh"):
-            # jax 0.4.x: constraints inside a partial-manual shard_map
-            # trip an XLA check (IsManualSubgroup); the hint is purely an
-            # optimization, so drop it there.
-            return x
         spec = _project_spec(spec, manual)
         try:
             return jax.lax.with_sharding_constraint(x, spec)

@@ -15,11 +15,7 @@ Two data-parallel modes, both RailX-mapped:
   ``flat`` (baseline psum), ``hierarchical`` (Eq. 8: RS(data) -> AR(pod)
   -> AG(data)), or ``compressed`` (int8 on the pod phase).  This is the
   paper-faithful executable form; for MoE archs use gspmd_fsdp (their EP
-  shard_map cannot nest inside another manual region).  On jax 0.4.x,
-  where XLA cannot compile a layer scan inside a partial-manual region
-  (hard process abort), manual_hier degrades to the GSPMD step with
-  DP-replicated parameters (same numerics, schedule skipped) — see
-  ``repro.compat.supports_partial_auto``.
+  shard_map cannot nest inside another manual region).
 
 Both modes support microbatch gradient accumulation (scan) and remat.
 """
@@ -31,8 +27,6 @@ import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
-
-from ..compat import shard_map, supports_partial_auto
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -115,12 +109,8 @@ def make_train_step(
     rules_overrides: Optional[Dict[str, Any]] = None,
 ) -> StepArtifacts:
     overrides = dict(rules_overrides or {})
-    if dp_mode == "manual_hier" and supports_partial_auto():
+    if dp_mode == "manual_hier":
         # params replicated over DP axes; batch sharding handled manually.
-        # (On jax 0.4.x manual_hier falls back to the GSPMD step below and
-        # keeps the fsdp/expert sharding rules: XLA then inserts the same
-        # per-layer gather/reduce-scatter as gspmd_fsdp, so the fallback
-        # is numerically identical to the reference mode.)
         overrides.setdefault("fsdp", None)
         overrides.setdefault("expert", None)
     rules = make_rules(tuple(mesh.shape.keys()), overrides)
@@ -207,15 +197,6 @@ def make_train_step(
     if dp_mode != "manual_hier":
         raise ValueError(dp_mode)
 
-    if not supports_partial_auto():
-        # jax 0.4.x cannot compile this model under a partial-manual
-        # shard_map at all (XLA aborts the process on the layer scan — see
-        # repro.compat.supports_partial_auto).  Fall back to the GSPMD
-        # step: parameters keep the fsdp sharding rules, XLA inserts the
-        # (already hierarchical, per the module docstring) gradient
-        # collectives, and only the explicit RailX schedule is skipped.
-        return gspmd_artifacts()
-
     # ---- manual_hier: explicit RailX schedule on the DP axes -------------
     intra, inter = ("data",), ("pod",)
     intra = tuple(a for a in intra if a in mesh.shape)
@@ -267,7 +248,7 @@ def make_train_step(
         lambda s: P(*(_keep_axes(s, manual_axes))), tree,
         is_leaf=lambda x: isinstance(x, P),
     )
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(no_dp(pspecs), no_dp(opt_specs), bspec),

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.data.pipeline import DataConfig, SyntheticLM, optimal_nll
+from repro.data.pipeline import DataConfig, SyntheticLM, _bigram_table, optimal_nll
 from repro.train import optimizer as opt_lib
 from repro.train.trainer import StragglerAlert, StragglerMonitor
 
@@ -25,6 +25,18 @@ def test_data_deterministic_and_sharded():
     assert not np.array_equal(s0["tokens"], s1["tokens"])
     # targets are next tokens
     np.testing.assert_array_equal(a["tokens"][:, 1:], a["targets"][:, :-1])
+
+
+def test_bigram_table_matches_one_draw():
+    """The table is built in row chunks; it must equal the one-draw form
+    (one (vocab, vocab) normal draw, top-8 boost by full sort)."""
+    cfg = DataConfig(vocab=2500, seq_len=4, global_batch=1)  # 3 chunks
+    rng = np.random.RandomState(cfg.seed)
+    logits = rng.randn(cfg.vocab, cfg.vocab) * cfg.bigram_temp
+    boost = np.zeros_like(logits)
+    np.put_along_axis(boost, np.argsort(-logits, axis=1)[:, :8], 4.0, axis=1)
+    p = np.exp(logits * 0.1 + boost)
+    np.testing.assert_array_equal(_bigram_table(cfg), p / p.sum(axis=1, keepdims=True))
 
 
 def test_optimal_nll_below_uniform():

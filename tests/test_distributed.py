@@ -75,13 +75,11 @@ def test_hierarchical_reduces_inter_node_bytes():
     assert data["hier"] * 3 < data["flat"], data  # ~4x fewer AR bytes
 
 
-def test_train_modes_agree():
-    """Runs un-xfailed on jax 0.4.x too: the partial-auto shard_map body
-    traces under ``repro.compat``'s degraded-collectives scope there, so
-    the hierarchical schedule lowers to plain psums instead of the
-    psum_scatter/all_gather forms whose SPMD partitioning aborts XLA."""
+def _train_modes_losses(attn_impl: str) -> dict:
+    """Three steps of gspmd_fsdp and of manual_hier + hierarchical on a
+    (pod, data, model) = (2, 2, 2) mesh, same parameters and batches."""
     out = run_py("""
-        import jax, jax.numpy as jnp, numpy as np, json
+        import dataclasses, jax, jax.numpy as jnp, numpy as np, json
         from repro.configs import get_smoke_config
         from repro.models.model_zoo import get_model
         from repro.train.optimizer import AdamWConfig, init as opt_init
@@ -89,7 +87,7 @@ def test_train_modes_agree():
         from repro.data.pipeline import DataConfig, SyntheticLM
         from repro.launch.mesh import make_mesh as _mk_mesh
         mesh = _mk_mesh((2, 2, 2), ("pod", "data", "model"))
-        cfg = get_smoke_config("qwen3-8b")
+        cfg = dataclasses.replace(get_smoke_config("qwen3-8b"), attn_impl=%r)
         zoo = get_model(cfg)
         ocfg = AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=20)
         data = SyntheticLM(DataConfig(vocab=cfg.vocab, seq_len=16, global_batch=8))
@@ -108,8 +106,25 @@ def test_train_modes_agree():
                 losses.append(float(m["loss"]))
             out[mode] = losses
         print(json.dumps(out))
-    """)
-    data = json.loads(out.strip().splitlines()[-1])
+    """ % attn_impl)
+    return json.loads(out.strip().splitlines()[-1])
+
+
+def test_train_modes_agree():
+    """The explicit RailX schedule (``manual_hier`` + hierarchical: RS on
+    ``data``, AR on ``pod``, AG on ``data`` inside a partial-manual
+    shard_map) trains to the same losses as the GSPMD FSDP step."""
+    data = _train_modes_losses("ref")
+    a = data["gspmd_fsdp"]
+    b = data["manual_hier"]
+    assert all(abs(x - y) < 1e-3 for x, y in zip(a, b)), data
+    assert a[-1] < a[0]  # learning
+
+
+def test_train_modes_agree_flash():
+    """Same, with the flash kernel: inside manual_hier's region it runs per
+    shard, in a nested shard_map over the remaining ``model`` axis."""
+    data = _train_modes_losses("flash")
     a = data["gspmd_fsdp"]
     b = data["manual_hier"]
     assert all(abs(x - y) < 1e-3 for x, y in zip(a, b)), data

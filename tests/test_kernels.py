@@ -32,7 +32,9 @@ def test_flash_attention_sweep(B, H, Hk, S, Dh, causal, window, dtype):
     q = jnp.array(RNG.randn(B, H, S, Dh), dtype)
     k = jnp.array(RNG.randn(B, Hk, S, Dh), dtype)
     v = jnp.array(RNG.randn(B, Hk, S, Dh), dtype)
-    out = flash_attention_fwd(q, k, v, causal=causal, window=window)
+    out = flash_attention_fwd(
+        q, k, v, causal=causal, window=window, interpret=True
+    )
     ref = attention_ref(q, k, v, causal=causal, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
@@ -50,7 +52,7 @@ def test_flash_attention_property(S, Dh, causal):
     q = jnp.array(RNG.randn(1, 2, S, Dh), jnp.float32)
     k = jnp.array(RNG.randn(1, 2, S, Dh), jnp.float32)
     v = jnp.array(RNG.randn(1, 2, S, Dh), jnp.float32)
-    out = flash_attention_fwd(q, k, v, causal=causal)
+    out = flash_attention_fwd(q, k, v, causal=causal, interpret=True)
     ref = attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
