@@ -243,6 +243,7 @@ def flash_attention_fwd_lse(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, Dh), jnp.float32),
         ],
+        name="flash_fwd_lse",
         interpret=interpret,
     )(q, k, v)
 
@@ -283,6 +284,7 @@ def flash_attention_bwd(
         out_specs=pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, Dh), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -312,6 +314,7 @@ def flash_attention_bwd(
             pltpu.VMEM((block_k, Dh), jnp.float32),
             pltpu.VMEM((block_k, Dh), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     # reduce over the GQA group back to kv heads
@@ -364,5 +367,6 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, Dh), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)

@@ -100,5 +100,6 @@ def mlstm_fwd(
             pltpu.VMEM((D,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
+        name="mlstm_fwd",
         interpret=interpret,
     )(q, k, v, i_gate, logf)

@@ -95,5 +95,6 @@ def ssd_fwd(
         out_specs=pl.BlockSpec((1, chunk, 1, P), lambda b, h, ic: (b, ic, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        name="ssd_fwd",
         interpret=interpret,
     )(x, dt, Bm, Cm, A)

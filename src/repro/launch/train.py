@@ -3,11 +3,20 @@
 Example (CPU, tiny):
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b --smoke \\
       --steps 50 --devices 8 --mesh 2,2,2 --axes pod,data,model
+
+``--profile-dir DIR`` records the run with the JAX profiler: the device's
+ops under the model's named scopes, and ``train_loop``'s host spans on the
+same clock (a ``train`` step annotation per step, with ``train.data``,
+``train.sync`` and ``train.checkpoint`` inside it), so a slow input, sync
+or checkpoint save shows beside the steps.  ``DIR`` holds one
+``*.xplane.pb`` (open it with XProf or TensorBoard's profile plugin, or
+read it with ``jax.profiler.ProfileData.from_file``).
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import sys
 
@@ -30,6 +39,8 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
+    ap.add_argument("--profile-dir", default="",
+                    help="record the run with the JAX profiler into this directory")
     args = ap.parse_args()
 
     if args.devices:
@@ -99,10 +110,13 @@ def main() -> None:
             }
             step += 1
 
-    res = train_loop(
-        arts.step_fn, params, opt, batches(), num_steps=args.steps,
-        start_step=start, ckpt=ckpt, straggler=StragglerMonitor(),
-    )
+    profile = (jax.profiler.trace(args.profile_dir) if args.profile_dir
+               else contextlib.nullcontext())
+    with profile:
+        res = train_loop(
+            arts.step_fn, params, opt, batches(), num_steps=args.steps,
+            start_step=start, ckpt=ckpt, straggler=StragglerMonitor(),
+        )
     print(
         f"done: {res.steps_done} steps, final loss {res.last_metrics.get('loss'):.4f}"
     )

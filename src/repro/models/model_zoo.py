@@ -47,17 +47,17 @@ class ModelZoo:
         """Next-token cross entropy over batch['targets'] with optional
         batch['loss_mask']; adds MoE aux loss."""
         logits, aux = self.forward(params, batch)
-        targets = batch["targets"]
-        V = logits.shape[-1]
-        logits32 = logits.astype(jnp.float32)
-        logz = jax.nn.logsumexp(logits32, axis=-1)
-        gold = jnp.take_along_axis(logits32, targets[..., None], axis=-1)[..., 0]
-        nll = logz - gold
-        mask = batch.get("loss_mask")
-        if mask is None:
-            loss = jnp.mean(nll)
-        else:
-            loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        with jax.named_scope("head"):
+            targets = batch["targets"]
+            logits32 = logits.astype(jnp.float32)
+            logz = jax.nn.logsumexp(logits32, axis=-1)
+            gold = jnp.take_along_axis(logits32, targets[..., None], axis=-1)[..., 0]
+            nll = logz - gold
+            mask = batch.get("loss_mask")
+            if mask is None:
+                loss = jnp.mean(nll)
+            else:
+                loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         total = loss + aux
         return total, {"nll": loss, "aux": aux}
 

@@ -154,19 +154,25 @@ def _layer_fwd(
     dt: DTypes,
 ) -> Tuple[jax.Array, jax.Array]:
     acfg = _attn_cfg(cfg)
-    h = C.rmsnorm(lp["ln1"], x)
-    attn_out = _attention_dynwin(
-        lp["attn"], acfg, h, positions, positions3, is_global, dt, cfg.attn_impl
-    )
-    x = x + attn_out
-    h = C.rmsnorm(lp["ln2"], x)
-    if "moe" in lp:
-        ffn_out, aux = moe_ffn(lp["moe"], _moe_cfg(cfg), h, dt)
-    else:
-        ffn_out, aux = C.swiglu(lp["ffn"], h, dt), jnp.zeros((), jnp.float32)
-    x = x + ffn_out
+    with jax.named_scope("attention"):
+        h = C.rmsnorm(lp["ln1"], x)
+        attn_out = _attention_dynwin(
+            lp["attn"], acfg, h, positions, positions3, is_global, dt, cfg.attn_impl
+        )
+        x = x + attn_out
+    with jax.named_scope(_ffn_scope(lp)):
+        h = C.rmsnorm(lp["ln2"], x)
+        if "moe" in lp:
+            ffn_out, aux = moe_ffn(lp["moe"], _moe_cfg(cfg), h, dt)
+        else:
+            ffn_out, aux = C.swiglu(lp["ffn"], h, dt), jnp.zeros((), jnp.float32)
+        x = x + ffn_out
     x = shard_hint(x, ("batch", "seq", "embed"))
     return x, aux
+
+
+def _ffn_scope(lp: Params) -> str:
+    return "moe" if "moe" in lp else "mlp"
 
 
 def _np_attention(q, k, v, causal, window, scale):
@@ -346,17 +352,30 @@ def _attention_dynwin(
     return C.linear(p["wo"], out, dt)
 
 
+def _embed(params: Params, cfg: ModelConfig, batch, dt: DTypes) -> jax.Array:
+    with jax.named_scope("embed"):
+        if "embeds" in batch:
+            return batch["embeds"].astype(cfg.compute_dtype)
+        x = C.embed(params["embed"], batch["tokens"], dt)
+        return x * jnp.asarray(math.sqrt(cfg.d_model), cfg.compute_dtype)
+
+
+def _head(params: Params, cfg: ModelConfig, x: jax.Array, dt: DTypes) -> jax.Array:
+    """Final norm and logits."""
+    with jax.named_scope("head"):
+        x = C.rmsnorm(params["final_norm"], x)
+        if cfg.tie_embeddings:
+            return C.unembed(params["embed"], x, dt)
+        return C.linear(params["lm_head"], x, dt)
+
+
 def forward(
     params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array]
 ) -> Tuple[jax.Array, jax.Array]:
     """batch: tokens (B,S) int32 [or embeds (B,S,D) for vlm stub],
     positions (B,S) optional, positions3 (3,B,S) for M-RoPE."""
     dt = _dt(cfg)
-    if "embeds" in batch:
-        x = batch["embeds"].astype(cfg.compute_dtype)
-    else:
-        x = C.embed(params["embed"], batch["tokens"], dt)
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.compute_dtype)
+    x = _embed(params, cfg, batch, dt)
     B, S, _ = x.shape
     positions = batch.get("positions")
     if positions is None:
@@ -376,14 +395,12 @@ def forward(
         x, aux_l = fwd(lp, cfg, x, positions, positions3, is_global, dt)
         return (x, aux + aux_l), None
 
-    (x, aux), _ = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), (params["layers"], flags)
-    )
-    x = C.rmsnorm(params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = C.unembed(params["embed"], x, dt)
-    else:
-        logits = C.linear(params["lm_head"], x, dt)
+    with jax.named_scope("layers"):
+        (x, aux), _ = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), (params["layers"], flags)
+        )
+    logits = _head(params, cfg, x, dt)
+    if not cfg.tie_embeddings:
         logits = shard_hint(logits, ("batch", "seq", "vocab"))
     return logits, aux
 
@@ -418,11 +435,7 @@ def decode_step(
     """One token step: batch has tokens (B,1) [or embeds (B,1,D)] and
     optionally positions3 (3,B,1)."""
     dt = _dt(cfg)
-    if "embeds" in batch:
-        x = batch["embeds"].astype(cfg.compute_dtype)
-    else:
-        x = C.embed(params["embed"], batch["tokens"], dt)
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), cfg.compute_dtype)
+    x = _embed(params, cfg, batch, dt)
     B, S, _ = x.shape
     index = cache["index"]
     positions = jnp.broadcast_to(index + jnp.arange(S)[None], (B, S))
@@ -433,30 +446,28 @@ def decode_step(
     def body(carry, xs):
         x = carry
         lp, ck, cv, is_global = xs
-        h = C.rmsnorm(lp["ln1"], x)
-        out, (nk, nv) = _decode_attention(
-            lp["attn"], acfg, cfg, h, positions, positions3, is_global,
-            (ck, cv), index, dt,
-        )
-        x = x + out
-        h = C.rmsnorm(lp["ln2"], x)
-        if "moe" in lp:
-            ffn_out, _ = moe_ffn(lp["moe"], _moe_cfg(cfg), h, dt)
-        else:
-            ffn_out = C.swiglu(lp["ffn"], h, dt)
-        x = x + ffn_out
+        with jax.named_scope("attention"):
+            h = C.rmsnorm(lp["ln1"], x)
+            out, (nk, nv) = _decode_attention(
+                lp["attn"], acfg, cfg, h, positions, positions3, is_global,
+                (ck, cv), index, dt,
+            )
+            x = x + out
+        with jax.named_scope(_ffn_scope(lp)):
+            h = C.rmsnorm(lp["ln2"], x)
+            if "moe" in lp:
+                ffn_out, _ = moe_ffn(lp["moe"], _moe_cfg(cfg), h, dt)
+            else:
+                ffn_out = C.swiglu(lp["ffn"], h, dt)
+            x = x + ffn_out
         return x, (nk, nv)
 
-    x, (nks, nvs) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"], flags)
-    )
-    x = C.rmsnorm(params["final_norm"], x)
-    if cfg.tie_embeddings:
-        logits = C.unembed(params["embed"], x, dt)
-    else:
-        logits = C.linear(params["lm_head"], x, dt)
+    with jax.named_scope("layers"):
+        x, (nks, nvs) = jax.lax.scan(
+            body, x, (params["layers"], cache["k"], cache["v"], flags)
+        )
     new_cache = {"k": nks, "v": nvs, "index": index + S}
-    return logits, new_cache
+    return _head(params, cfg, x, dt), new_cache
 
 
 def _decode_attention(
@@ -477,22 +488,25 @@ def _decode_attention(
     else:
         q = C.apply_rope(q, positions, acfg.rope_theta)
         k = C.apply_rope(k, positions, acfg.rope_theta)
-    ck, cv = kv_cache
-    ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), index, axis=1)
-    cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), index, axis=1)
     scale = 1.0 / math.sqrt(Dh)
-    Skv = ck.shape[1]
     group = H // Hk
-    qf = q.astype(jnp.float32) * scale
-    qg = qf.reshape(B, S, Hk, group, Dh)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck.astype(jnp.float32))
-    qpos = jnp.arange(S)[:, None] + index
-    kpos = jnp.arange(Skv)[None, :]
-    mask = kpos <= qpos
-    if acfg.window is not None:
-        mask = mask & ((kpos > qpos - acfg.window) | is_global)
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, cv.astype(jnp.float32))
+    with jax.named_scope("kv_cache"):
+        # the step's cache traffic: write the new K/V, then read the whole
+        # cache in the two products over it
+        ck, cv = kv_cache
+        ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), index, axis=1)
+        cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), index, axis=1)
+        Skv = ck.shape[1]
+        qf = q.astype(jnp.float32) * scale
+        qg = qf.reshape(B, S, Hk, group, Dh)
+        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck.astype(jnp.float32))
+        qpos = jnp.arange(S)[:, None] + index
+        kpos = jnp.arange(Skv)[None, :]
+        mask = kpos <= qpos
+        if acfg.window is not None:
+            mask = mask & ((kpos > qpos - acfg.window) | is_global)
+        logits = jnp.where(mask[None, None, None], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, cv.astype(jnp.float32))
     out = out.reshape(B, S, H * Dh).astype(x.dtype)
     return C.linear(p["wo"], out, dt), (ck, cv)

@@ -1,5 +1,15 @@
 """repro.obs — observability for the simulator + scheduler stack.
 
+It serves the RailX twin (``core/``, ``arch/``, ``cluster/``): host-side
+code whose spans need no device clock.  The JAX path that runs on the
+chip (``models/``, ``kernels/``, ``train/``, ``serve/``) traces through
+the JAX profiler instead: ``jax.named_scope`` names its device ops
+(``embed``, ``layers``, ``attention``, ``mlp``, ``moe``, ``head``,
+``kv_cache``, ``optimizer``, ``grad_reduce``), every ``pallas_call``
+carries a ``name=``, and ``train_loop``'s host spans (a ``train`` step
+annotation with ``train.data``, ``train.sync`` and ``train.checkpoint``)
+land on the device trace's clock (``launch/train.py --profile-dir``).
+
 Three layers, all optional and all zero-cost when unused:
 
 * **Tracing** (``tracer``): a :class:`Tracer` emitting structured

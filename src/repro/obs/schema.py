@@ -43,10 +43,6 @@ KNOWN_SPANS: Dict[str, Tuple[str, ...]] = {
         "serving.autoscale",     # autoscaler decision on a rate sample
         "serving.place",         # replica placement attempt
     ),
-    "serve": (
-        "serve.prefill",         # one prefill launch (serve_step)
-        "serve.decode_step",     # one decode step launch (serve_step)
-    ),
     "launch": (
         "roofline.parse",        # HLO text parse inside analyze_hlo
     ),

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.model_zoo import ModelZoo
-from ..obs import get_tracer
 from ..parallel.sharding import logical_spec_tree, make_rules, use_rules
 from ..train.train_step import batch_specs_tree
 
@@ -78,23 +77,7 @@ def make_serve_step(
 
     jit_prefill = jax.jit(prefill, in_shardings=(param_sharding, batch_sharding))
 
-    # thin host-side wrappers: spans inside the jitted bodies would only
-    # fire at trace time, so the launches are what gets instrumented
-    def decode_fn(params, cache, batch):
-        trc = get_tracer()
-        if not trc.enabled:
-            return jit_decode(params, cache, batch)
-        with trc.span("serve.decode_step", cat="serve"):
-            return jit_decode(params, cache, batch)
-
-    def prefill_fn(params, batch):
-        trc = get_tracer()
-        if not trc.enabled:
-            return jit_prefill(params, batch)
-        with trc.span("serve.prefill", cat="serve"):
-            return jit_prefill(params, batch)
-
-    return ServeArtifacts(decode_fn, prefill_fn, param_sharding, cache_sharding, rules)
+    return ServeArtifacts(jit_decode, jit_prefill, param_sharding, cache_sharding, rules)
 
 
 # ---------------------------------------------------------------------------

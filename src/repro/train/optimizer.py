@@ -66,35 +66,36 @@ def global_norm(tree) -> jax.Array:
 def apply(
     cfg: AdamWConfig, state: AdamWState, params, grads
 ) -> Tuple[Any, AdamWState, Dict[str, jax.Array]]:
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-12))
-    step = state.step + 1
-    lr = lr_schedule(cfg, step)
-    b1c = 1 - cfg.b1 ** step.astype(jnp.float32)
-    b2c = 1 - cfg.b2 ** step.astype(jnp.float32)
+    with jax.named_scope("optimizer"):
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-12))
+        step = state.step + 1
+        lr = lr_schedule(cfg, step)
+        b1c = 1 - cfg.b1 ** step.astype(jnp.float32)
+        b2c = 1 - cfg.b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, mu, nu):
-        g = g.astype(jnp.float32) * scale
-        mu = cfg.b1 * mu + (1 - cfg.b1) * g.astype(cfg.moment_dtype)
-        nu = cfg.b2 * nu + (1 - cfg.b2) * jnp.square(g).astype(cfg.moment_dtype)
-        mhat = mu.astype(jnp.float32) / b1c
-        vhat = nu.astype(jnp.float32) / b2c
-        delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
-        if p.ndim >= 2:  # decoupled decay on matrices only
-            delta = delta + cfg.weight_decay * p.astype(jnp.float32)
-        new_p = (p.astype(jnp.float32) - lr * delta).astype(p.dtype)
-        return new_p, mu, nu
+        def upd(p, g, mu, nu):
+            g = g.astype(jnp.float32) * scale
+            mu = cfg.b1 * mu + (1 - cfg.b1) * g.astype(cfg.moment_dtype)
+            nu = cfg.b2 * nu + (1 - cfg.b2) * jnp.square(g).astype(cfg.moment_dtype)
+            mhat = mu.astype(jnp.float32) / b1c
+            vhat = nu.astype(jnp.float32) / b2c
+            delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
+            if p.ndim >= 2:  # decoupled decay on matrices only
+                delta = delta + cfg.weight_decay * p.astype(jnp.float32)
+            new_p = (p.astype(jnp.float32) - lr * delta).astype(p.dtype)
+            return new_p, mu, nu
 
-    flat_p, tdef = jax.tree_util.tree_flatten(params)
-    flat_g = tdef.flatten_up_to(grads)
-    flat_mu = tdef.flatten_up_to(state.mu)
-    flat_nu = tdef.flatten_up_to(state.nu)
-    out = [upd(p, g, m, n) for p, g, m, n in zip(flat_p, flat_g, flat_mu, flat_nu)]
-    new_params = tdef.unflatten([o[0] for o in out])
-    new_mu = tdef.unflatten([o[1] for o in out])
-    new_nu = tdef.unflatten([o[2] for o in out])
-    metrics = {"grad_norm": gnorm, "lr": lr}
-    return new_params, AdamWState(step, new_mu, new_nu), metrics
+        flat_p, tdef = jax.tree_util.tree_flatten(params)
+        flat_g = tdef.flatten_up_to(grads)
+        flat_mu = tdef.flatten_up_to(state.mu)
+        flat_nu = tdef.flatten_up_to(state.nu)
+        out = [upd(p, g, m, n) for p, g, m, n in zip(flat_p, flat_g, flat_mu, flat_nu)]
+        new_params = tdef.unflatten([o[0] for o in out])
+        new_mu = tdef.unflatten([o[1] for o in out])
+        new_nu = tdef.unflatten([o[2] for o in out])
+        metrics = {"grad_norm": gnorm, "lr": lr}
+        return new_params, AdamWState(step, new_mu, new_nu), metrics
 
 
 def state_specs(param_specs_tree) -> AdamWState:

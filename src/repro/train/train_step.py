@@ -230,7 +230,8 @@ def make_train_step(
 
     def body(params, opt_state, batch):
         loss, metrics, grads = accum_grads(loss_fn, params, batch)
-        grads = reduce_grads(grads)
+        with jax.named_scope("grad_reduce"):
+            grads = reduce_grads(grads)
         loss = jax.lax.pmean(loss, dp_axes)
         metrics = jax.tree_util.tree_map(lambda m: jax.lax.pmean(m, dp_axes), metrics)
         new_params, new_opt, opt_metrics = opt_lib.apply(

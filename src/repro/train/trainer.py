@@ -86,28 +86,33 @@ def train_loop(
     metrics_host: Dict[str, float] = {}
     step = start_step
     for step in range(start_step, num_steps):
-        batch = next(batches)
-        t0 = time.perf_counter()
-        params, opt_state, metrics = step_fn(params, opt_state, batch)
-        jax.block_until_ready(metrics["loss"])
-        dt = time.perf_counter() - t0
-        if straggler is not None:
-            straggler.observe(dt)
-        if step % log_every == 0 or step == num_steps - 1:
-            metrics_host = {k: float(v) for k, v in metrics.items()}
-            metrics_host["step_time_s"] = dt
-            history.append({"step": step, **metrics_host})
-            log_fn(
-                f"step {step:6d} loss {metrics_host['loss']:.4f} "
-                f"gnorm {metrics_host.get('grad_norm', 0):.3f} {dt*1e3:.0f} ms"
-            )
-        if ckpt is not None and (step + 1) % ckpt.every_steps == 0:
-            ckpt_lib.save(
-                ckpt.directory, step + 1,
-                {"params": params, "opt": opt_state},
-                extra={"step": step + 1},
-            )
-            _gc_checkpoints(ckpt)
+        # host spans on the profiler's clock, beside the device's ops
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            with jax.profiler.TraceAnnotation("train.data"):
+                batch = next(batches)
+            t0 = time.perf_counter()
+            params, opt_state, metrics = step_fn(params, opt_state, batch)
+            with jax.profiler.TraceAnnotation("train.sync"):
+                jax.block_until_ready(metrics["loss"])
+            dt = time.perf_counter() - t0
+            if straggler is not None:
+                straggler.observe(dt)
+            if step % log_every == 0 or step == num_steps - 1:
+                metrics_host = {k: float(v) for k, v in metrics.items()}
+                metrics_host["step_time_s"] = dt
+                history.append({"step": step, **metrics_host})
+                log_fn(
+                    f"step {step:6d} loss {metrics_host['loss']:.4f} "
+                    f"gnorm {metrics_host.get('grad_norm', 0):.3f} {dt*1e3:.0f} ms"
+                )
+            if ckpt is not None and (step + 1) % ckpt.every_steps == 0:
+                with jax.profiler.TraceAnnotation("train.checkpoint"):
+                    ckpt_lib.save(
+                        ckpt.directory, step + 1,
+                        {"params": params, "opt": opt_state},
+                        extra={"step": step + 1},
+                    )
+                    _gc_checkpoints(ckpt)
     return TrainResult(step + 1 - start_step, metrics_host, history)
 
 

@@ -10,6 +10,7 @@ import textwrap
 import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+TESTS = os.path.abspath(os.path.dirname(__file__))
 
 
 def run_py(code: str, devices: int = 8, timeout: int = 600) -> str:
@@ -171,3 +172,44 @@ def test_pipeline_parallel_forward():
     """)
     data = json.loads(out.strip().splitlines()[-1])
     assert data["err"] < 1e-4, data
+
+
+def test_gradient_collectives_sit_under_grad_reduce():
+    """``manual_hier`` on a (pod, data) = (2, 2) mesh: every collective
+    that moves gradients is under the ``grad_reduce`` scope, for each RailX
+    schedule; the only others are the scalar means of the loss and its
+    metrics."""
+    out = run_py("""
+        import json, sys
+        sys.path.insert(0, %r)
+        import jax, numpy as np
+        import hlo_scopes as scopes
+        from repro.configs import get_smoke_config
+        from repro.launch.mesh import make_mesh
+        from repro.models.model_zoo import get_model
+        from repro.train import optimizer as opt_lib
+        from repro.train.train_step import make_train_step
+        mesh = make_mesh((2, 2), ("pod", "data"))
+        zoo = get_model(get_smoke_config("qwen3-8b"))
+        ocfg = opt_lib.AdamWConfig()
+        ex = {"tokens": np.zeros((8, 16), np.int32), "targets": np.zeros((8, 16), np.int32)}
+        params = jax.eval_shape(lambda: zoo.init(jax.random.PRNGKey(0)))
+        shapes = lambda t, s: jax.tree_util.tree_map(
+            lambda a, x: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=x), t, s)
+        out = {}
+        for sched in ("flat", "hierarchical", "compressed"):
+            arts = make_train_step(zoo, ocfg, mesh, ex, dp_mode="manual_hier", schedule=sched)
+            batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=arts.batch_sharding[k])
+                     for k, v in ex.items()}
+            hlo = arts.step_fn.lower(
+                shapes(params, arts.param_sharding),
+                shapes(jax.eval_shape(lambda p: opt_lib.init(ocfg, p), params), arts.opt_sharding),
+                batch).compile().as_text()
+            out[sched] = [[scopes.scope_of(ins.op_name), scopes.is_scalar(ins)]
+                          for ins in scopes.instructions(hlo) if scopes.is_collective(ins)]
+        print(json.dumps(out))
+    """ % TESTS, devices=4)
+    found = json.loads(out.strip().splitlines()[-1])
+    for sched, colls in found.items():
+        assert any(scope == "grad_reduce" for scope, _ in colls), (sched, colls)
+        assert all(scope == "grad_reduce" or scalars for scope, scalars in colls), (sched, colls)

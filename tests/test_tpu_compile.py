@@ -1,4 +1,5 @@
-"""The flash-attention kernels compile for a TPU v5e at qwen3-8b widths.
+"""The flash-attention kernels compile for a TPU v5e at qwen3-8b widths,
+and the program's scopes and kernel names reach the compiled TPU HLO.
 
 Interpret mode never checks the TPU lowering's tiling rules, so these
 tests compile each kernel with ``interpret=False`` for a described (not
@@ -8,11 +9,13 @@ only the test worker that is handed this file loads the TPU library.
 """
 
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.kernels.flash_attention.flash_attention import (
     flash_attention_bwd,
@@ -94,3 +97,107 @@ def test_flash_bwd_compiles(group, one_chip, no_persistent_cache):
         q, kv, kv, q, lse, q,
     )
     assert n >= 2  # the dq kernel and the dk/dv kernel
+
+
+# ---------------------------------------------------------------------------
+# named scopes and kernel names in the compiled step programs
+# ---------------------------------------------------------------------------
+
+import hlo_scopes as scopes  # noqa: E402
+
+BENCH = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+
+# head_dim 128 and 256 tokens: the widths the kernels' TPU tiling takes
+TINY = dict(name="tiny", family="dense", num_layers=2, d_model=256, heads=2, kv_heads=1,
+            head_dim=128, d_ff=512, vocab=512, qk_norm=True, tie_embeddings=False,
+            param_dtype=jnp.float32, compute_dtype=jnp.bfloat16, remat=True, attn_impl="flash")
+TB, TS, CACHE = 2, 256, 512
+
+
+def _shapes(tree, shardings):
+    return jax.tree_util.tree_map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), tree, shardings)
+
+
+@pytest.fixture(scope="module")
+def compiled_steps(topo, no_persistent_cache):
+    """The compiled HLO text of a tiny dense model's train step
+    (``make_train_step``) and decode step (``make_serve_step``) for one
+    described chip, with the flash kernels compiled, not interpreted."""
+    from repro.configs.base import ModelConfig
+    from repro.kernels.flash_attention import ops
+    from repro.models.model_zoo import get_model
+    from repro.serve.serve_step import make_serve_step
+    from repro.train import optimizer as opt_lib
+    from repro.train.train_step import make_train_step
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    zoo = get_model(ModelConfig(**TINY))
+    ocfg = opt_lib.AdamWConfig()
+    params = jax.eval_shape(lambda: zoo.init(jax.random.PRNGKey(0)))
+    example = {"tokens": np.zeros((TB, TS), np.int32), "targets": np.zeros((TB, TS), np.int32)}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_on_cpu", lambda: False)
+        arts = make_train_step(zoo, ocfg, mesh, example)
+        batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=arts.batch_sharding[k])
+                 for k, v in example.items()}
+        train = arts.step_fn.lower(
+            _shapes(params, arts.param_sharding),
+            _shapes(jax.eval_shape(lambda p: opt_lib.init(ocfg, p), params), arts.opt_sharding),
+            batch).compile().as_text()
+    cache = jax.eval_shape(lambda: zoo.init_cache(TB, CACHE))
+    sarts = make_serve_step(zoo, mesh, {"tokens": np.zeros((TB, 1), np.int32)}, cache_example=cache)
+    decode = sarts.decode_fn.lower(
+        _shapes(params, sarts.param_sharding), _shapes(cache, sarts.cache_sharding),
+        {"tokens": jax.ShapeDtypeStruct((TB, 1), jnp.int32, sharding=NamedSharding(mesh, P()))},
+    ).compile().as_text()
+    return {"train": train, "decode": decode}
+
+
+def test_every_kernel_call_carries_its_name(compiled_steps, monkeypatch):
+    """Every kernel call names its kernel, and the benchmark's
+    ``flash_kind``, which tells the flash kernels apart by signature,
+    agrees with the name."""
+    monkeypatch.syspath_prepend(str(BENCH))
+    from harness.reduce import flash_kind
+
+    kinds = []
+    for ins in scopes.instructions(compiled_steps["train"]):
+        if 'custom_call_target="tpu_custom_call"' not in ins.text:
+            continue
+        name = scopes.kernel_of(ins.op_name)
+        assert name is not None, ins.op_name
+        assert name == f"flash_{flash_kind(ins.text)}", (name, ins.text[:200])
+        assert scopes.scope_of(ins.op_name) == "attention", ins.op_name
+        kinds.append(name)
+    # forward, its recomputation under remat, and both backward kernels
+    assert sorted(set(kinds)) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd_lse"]
+
+
+@pytest.mark.parametrize("step,want", [
+    ("train", {"attention", "mlp", "head"}),
+    ("decode", {"attention", "mlp", "head", "kv_cache"}),
+])
+def test_every_matmul_is_under_a_scope(compiled_steps, step, want):
+    """Every dot (a ``convolution`` on the TPU) sits under one of the
+    program's scopes, and the layers' products are all found."""
+    found = set()
+    for ins in scopes.instructions(compiled_steps[step]):
+        if ins.opcode in ("dot", "convolution"):
+            scope = scopes.scope_of(ins.op_name)
+            assert scope is not None, (ins.op_name, ins.text[:200])
+            found.add(scope)
+    assert found == want
+
+
+def test_decode_cache_write_is_under_kv_cache(compiled_steps):
+    """The step's K/V write into the cache is the ``kv_cache`` scope's; the
+    scan's stacking of the layers' caches is ``layers``'s."""
+    found = {scopes.scope_of(ins.op_name) for ins in scopes.instructions(compiled_steps["decode"])
+             if ins.opcode == "dynamic-update-slice"}
+    assert found == {"kv_cache", "layers"}
+
+
+def test_train_step_has_each_layer_scope(compiled_steps):
+    found = {scopes.scope_of(ins.op_name) for ins in scopes.instructions(compiled_steps["train"])}
+    assert {"embed", "layers", "attention", "mlp", "head", "optimizer"} <= found
