@@ -7,7 +7,12 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.flash_attention.flash_attention import flash_attention_fwd
+from repro.kernels.flash_attention.flash_attention import (
+    flash_attention_fwd,
+    flash_attention_fwd_lse,
+    kv_block_range,
+    visited_share,
+)
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.mlstm.ops import mlstm
@@ -18,28 +23,112 @@ from repro.kernels.ssd.ref import ssd_ref
 RNG = np.random.RandomState(0)
 
 
+def _masked_logsumexp(q, k, *, causal, window, q_offset):
+    """logsumexp over keys of the masked, scaled logits: (B, H, Sq, 1)."""
+    B, H, Sq, Dh = q.shape
+    Hk, Skv = k.shape[1], k.shape[2]
+    qf = q.astype(jnp.float32).reshape(B, Hk, H // Hk, Sq, Dh) * Dh ** -0.5
+    logits = jnp.einsum("bhgqd,bhkd->bhgqk", qf, k.astype(jnp.float32))
+    qpos = jnp.arange(Sq)[:, None] + q_offset
+    kpos = jnp.arange(Skv)[None, :]
+    mask = jnp.ones((Sq, Skv), bool)
+    if causal:
+        mask &= kpos <= qpos
+    if window is not None:
+        mask &= kpos > qpos - window
+    logits = jnp.where(mask, logits, -1e30)
+    return jax.nn.logsumexp(logits, axis=-1, keepdims=True).reshape(B, H, Sq, 1)
+
+
+@pytest.mark.parametrize("entry", ["fwd", "fwd_lse"])
 @pytest.mark.parametrize(
-    "B,H,Hk,S,Dh,causal,window,dtype",
+    "B,H,Hk,Sq,Skv,q_offset,Dh,causal,window,blocks,dtype",
     [
-        (2, 4, 2, 256, 64, True, None, jnp.float32),
-        (1, 2, 1, 128, 128, True, 64, jnp.float32),
-        (2, 2, 2, 256, 32, False, None, jnp.float32),
-        (1, 8, 4, 512, 64, True, 128, jnp.float32),
-        (2, 4, 4, 256, 64, True, None, jnp.bfloat16),
+        (2, 4, 2, 256, 256, 0, 64, True, None, None, jnp.float32),
+        (1, 2, 1, 128, 128, 0, 128, True, 64, None, jnp.float32),
+        (2, 2, 2, 256, 256, 0, 32, False, None, None, jnp.float32),
+        (1, 8, 4, 512, 512, 0, 64, True, 128, None, jnp.float32),
+        (2, 4, 4, 256, 256, 0, 64, True, None, None, jnp.bfloat16),
+        # Sq != Skv at a static offset, GQA group 4, block_q != block_k
+        (1, 4, 1, 128, 512, 384, 64, True, None, (64, 128), jnp.float32),
+        # keys past the last query are pruned; offsets off the block grid
+        # put tiles on each edge of the mask and of the visited range
+        (1, 4, 4, 128, 384, 97, 64, True, 96, (32, 64), jnp.float32),
+        (1, 2, 2, 128, 256, 94, 64, True, None, (32, 64), jnp.float32),
+        # window smaller than one block, and larger than a block
+        (1, 2, 2, 256, 256, 0, 64, True, 32, (64, 64), jnp.float32),
+        (1, 2, 2, 256, 256, 0, 64, True, 96, (64, 32), jnp.float32),
+        # no causal mask: every tile runs; with a window, a lower bound only
+        (2, 2, 2, 256, 256, 0, 64, False, None, (128, 64), jnp.float32),
+        (1, 2, 2, 256, 256, 0, 64, False, 63, (64, 64), jnp.float32),
+        (1, 8, 2, 256, 256, 0, 64, True, None, (64, 128), jnp.bfloat16),
     ],
 )
-def test_flash_attention_sweep(B, H, Hk, S, Dh, causal, window, dtype):
-    q = jnp.array(RNG.randn(B, H, S, Dh), dtype)
-    k = jnp.array(RNG.randn(B, Hk, S, Dh), dtype)
-    v = jnp.array(RNG.randn(B, Hk, S, Dh), dtype)
-    out = flash_attention_fwd(
-        q, k, v, causal=causal, window=window, interpret=True
-    )
-    ref = attention_ref(q, k, v, causal=causal, window=window)
+def test_flash_attention_sweep(
+    entry, B, H, Hk, Sq, Skv, q_offset, Dh, causal, window, blocks, dtype
+):
+    q = jnp.array(RNG.randn(B, H, Sq, Dh), dtype)
+    k = jnp.array(RNG.randn(B, Hk, Skv, Dh), dtype)
+    v = jnp.array(RNG.randn(B, Hk, Skv, Dh), dtype)
+    kw = dict(causal=causal, window=window, q_offset=q_offset)
+    if blocks is not None:
+        kw.update(block_q=blocks[0], block_k=blocks[1])
+    if entry == "fwd":
+        out = flash_attention_fwd(q, k, v, interpret=True, **kw)
+    else:
+        out, lse = flash_attention_fwd_lse(q, k, v, interpret=True, **kw)
+        assert lse.shape == (B, H, Sq, 1) and lse.dtype == jnp.float32
+        want = _masked_logsumexp(q, k, causal=causal, window=window, q_offset=q_offset)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=1e-4, rtol=1e-5)
+    ref = attention_ref(q, k, v, causal=causal, window=window, q_offset=q_offset)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert out.shape == q.shape and out.dtype == q.dtype
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=tol
     )
+
+
+@pytest.mark.parametrize(
+    "Sq,Skv,q_offset,block_q,block_k,causal",
+    [
+        (64, 64, 0, 16, 16, True),
+        (64, 64, 0, 32, 8, True),
+        (64, 64, 0, 8, 32, True),
+        (32, 96, 41, 8, 16, True),
+        (32, 96, 64, 16, 8, True),
+        (64, 64, 0, 16, 8, False),
+        (48, 48, 0, 16, 16, False),
+    ],
+)
+def test_kv_block_range_matches_the_mask(Sq, Skv, q_offset, block_q, block_k, causal):
+    """For every window up to twice a block: a tile outside the range has
+    no unmasked (query, key) pair; every tile inside it has at least one."""
+    qpos = np.arange(Sq)[:, None] + q_offset
+    kpos = np.arange(Skv)[None, :]
+    for window in [None, *range(1, 2 * max(block_q, block_k) + 3)]:
+        mask = np.ones((Sq, Skv), bool)
+        if causal:
+            mask &= kpos <= qpos
+        if window is not None:
+            mask &= kpos > qpos - window
+        for iq in range(Sq // block_q):
+            lo, hi = kv_block_range(
+                iq, causal=causal, window=window, q_offset=q_offset,
+                block_q=block_q, block_k=block_k, kv_len=Skv,
+            )
+            for ik in range(Skv // block_k):
+                tile = mask[iq * block_q:(iq + 1) * block_q, ik * block_k:(ik + 1) * block_k]
+                assert bool(tile.any()) == (int(lo) <= ik <= int(hi)), (window, iq, ik)
+
+
+@pytest.mark.parametrize("block,share", [(128, 0.515625), (256, 0.53125), (512, 0.5625)])
+def test_visited_share_at_4k(block, share):
+    """Causal self-attention at S 4,096: the share of tiles the forward runs."""
+    got = visited_share(
+        q_len=4096, kv_len=4096, causal=True, window=None, q_offset=0,
+        block_q=block, block_k=block,
+    )
+    assert got == share
 
 
 @given(
