@@ -15,12 +15,29 @@ import jax.numpy as jnp
 
 @dataclasses.dataclass(frozen=True)
 class MoEParams:
-    num_experts: int
+    num_experts: int             # experts the router scores
     top_k: int
     d_ff: int                    # per-expert intermediate
     capacity_factor: float = 1.25
     aux_loss_coeff: float = 0.01
-    num_shared_experts: int = 0
+    num_shared_experts: int = 0  # one shared SwiGLU of width d_ff * this
+    scoring: str = "softmax"     # softmax | sigmoid (DeepSeek-V3)
+    routed_scale: float = 1.0    # gates times this (routed_scaling_factor)
+    # experts this chip holds, [first_held, first_held + held_experts) of
+    # num_experts: the one-chip dropless layer computes only their part of
+    # the result.  None keeps every expert here (capacity dispatch / EP).
+    held_experts: Optional[int] = None
+    first_held: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAParams:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values come
+    from a shared latent of ``kv_lora_rank`` plus one rotary key head."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int                     # the query projection is full rank
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,8 +57,11 @@ class ModelConfig:
     # gemma3-style local:global attention
     sliding_window: Optional[int] = None    # window for local layers
     global_every: Optional[int] = None      # every Nth layer is global
-    # MoE
+    rms_norm_eps: float = 1e-6
+    # MoE (``moe`` and ``mla`` also take plain dicts of their fields)
     moe: Optional[MoEParams] = None
+    first_dense_layers: int = 0  # leading layers with a dense FFN of d_ff
+    mla: Optional[MLAParams] = None
     moe_ep_axis: str = "data"    # mesh axis carrying EP all-to-all
     moe_tp: bool = True          # shard expert FFN intermediate over TP
     moe_token_scatter: bool = False  # shard expert queues over TP (M4)
@@ -62,15 +82,45 @@ class ModelConfig:
     remat: bool = False
     attn_impl: str = "ref"                  # ref | flash | flash_stub
 
+    def __post_init__(self):
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoEParams(**self.moe))
+        if isinstance(self.mla, dict):
+            object.__setattr__(self, "mla", MLAParams(**self.mla))
+
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.heads)
 
+    @property
+    def attn_widths(self) -> Tuple[int, int]:
+        """(query/key, value) widths of one attention head."""
+        if self.mla is not None:
+            a = self.mla
+            return a.qk_nope_head_dim + a.qk_rope_head_dim, a.v_head_dim
+        return self.resolved_head_dim, self.resolved_head_dim
+
+    def _attn_params(self) -> int:
+        D, Dh = self.d_model, self.resolved_head_dim
+        if self.mla is not None:
+            a, H = self.mla, self.heads
+            return (D * H * (a.qk_nope_head_dim + a.qk_rope_head_dim)
+                    + D * (a.kv_lora_rank + a.qk_rope_head_dim) + a.kv_lora_rank
+                    + a.kv_lora_rank * H * (a.qk_nope_head_dim + a.v_head_dim)
+                    + H * a.v_head_dim * D)
+        return D * Dh * (self.heads * 2 + self.kv_heads * 2)
+
+    def _moe_layer_ffn(self, experts: int) -> int:
+        """FFN parameters of one MoE layer with ``experts`` routed experts
+        counted: those, the shared expert and the router."""
+        D, m = self.d_model, self.moe
+        return 3 * D * m.d_ff * (experts + m.num_shared_experts) + D * m.num_experts
+
     def param_count(self) -> float:
-        """Approximate parameter count (for 6ND model FLOPs)."""
+        """Approximate parameter count (for 6ND model FLOPs); an MoE layer
+        counts the experts held here."""
         D, L, V = self.d_model, self.num_layers, self.vocab
-        Dh = self.resolved_head_dim
-        attn = D * Dh * (self.heads * 2 + self.kv_heads * 2)
+        attn = self._attn_params()
         if self.family == "xlstm":
             per_layer = 4 * D * D + 2 * D * self.heads
         elif self.family == "hybrid":
@@ -78,11 +128,13 @@ class ModelConfig:
             per_layer = D * (2 * d_inner + 2 * self.ssm_state + d_inner // self.mamba_head_dim) + d_inner * D
         else:
             per_layer = attn
-        if self.moe is not None:
-            per_layer += 3 * D * self.moe.d_ff * self.moe.num_experts + D * self.moe.num_experts
-        elif self.family not in ("xlstm",):
-            per_layer += 3 * D * self.d_ff
         total = L * per_layer + V * D * (1 if self.tie_embeddings else 2)
+        if self.moe is not None:
+            held = self.moe.held_experts or self.moe.num_experts
+            n_dense = self.first_dense_layers
+            total += (L - n_dense) * self._moe_layer_ffn(held) + n_dense * 3 * D * self.d_ff
+        elif self.family not in ("xlstm",):
+            total += L * 3 * D * self.d_ff
         if self.family == "whisper":
             enc = self.enc_layers * (attn + 2 * D * self.d_ff)
             dec_extra = L * attn  # cross attention
@@ -93,12 +145,10 @@ class ModelConfig:
         """MoE: parameters touched per token (6*N_active*D FLOPs rule)."""
         if self.moe is None:
             return self.param_count()
-        D, L = self.d_model, self.num_layers
-        dense = self.param_count() - L * 3 * D * self.moe.d_ff * self.moe.num_experts
-        active_ffn = L * 3 * D * self.moe.d_ff * (
-            self.moe.top_k + self.moe.num_shared_experts
-        )
-        return float(dense + active_ffn)
+        held = self.moe.held_experts or self.moe.num_experts
+        moe_layers = self.num_layers - self.first_dense_layers
+        return float(self.param_count() + moe_layers * (
+            self._moe_layer_ffn(self.moe.top_k) - self._moe_layer_ffn(held)))
 
 
 @dataclasses.dataclass(frozen=True)

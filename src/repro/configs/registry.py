@@ -14,7 +14,7 @@ from . import (
     gemma3_4b,
     granite_20b,
     llama3_2_3b,
-    moonshot_v1_16b_a3b,
+    moonlight_16b_a3b,
     paper_llama3_moe,
     qwen2_vl_2b,
     qwen3_8b,
@@ -27,7 +27,7 @@ from . import (
 _MODULES = {
     "xlstm-125m": xlstm_125m,
     "qwen3-moe-235b-a22b": qwen3_moe_235b_a22b,
-    "moonshot-v1-16b-a3b": moonshot_v1_16b_a3b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
     "qwen2-vl-2b": qwen2_vl_2b,
     "qwen3-8b": qwen3_8b,
     "llama3.2-3b": llama3_2_3b,

@@ -27,6 +27,12 @@ Forward.  One kernel body serves ``flash_attention_fwd`` and
 Backward.  Fixed (128, head_dim) q- and kv-tiles, every tile visited:
 fully-masked k-tiles still run and their DMAs are issued.
 
+Head widths.  Queries and keys share one width and values may have
+another (multi-head latent attention: 192 and 128).  Every block, output
+and scratch that holds values, outputs or their gradients (v, o, dO, dV,
+``acc``) is taken at the value width of ``v``; q, k, dQ and dK at the
+width of ``q``.
+
 The per-row softmax statistics (``lse`` from the forward, ``delta`` in the
 backward) are carried as ``(B, H, Sq, 1)`` arrays: the TPU lowering needs
 the last two block dims to be multiples of (8, 128) or equal to the full
@@ -89,18 +95,23 @@ def visited_share(*, q_len, kv_len, causal, window, q_offset, block_q, block_k) 
     return visited / (nq * nk)
 
 
-def _fwd_vmem_bytes(block_q: int, block_k: int, head_dim: int, itemsize: int) -> int:
+def _fwd_vmem_bytes(block_q: int, block_k: int, head_dim: int, itemsize: int,
+                    v_dim: Optional[int] = None) -> int:
     """VMEM of one forward grid step: double-buffered q, k, v, o and lse
     blocks (lse padded to 128 lanes), the m/l/acc scratch, the f32 copies
     of q, k, v, and two f32 (block_q, block_k) tiles (scores and
-    probabilities)."""
-    blocks = 2 * ((2 * block_q + 2 * block_k) * head_dim * itemsize + block_q * LANES * 4)
-    scratch = (2 * LANES + head_dim) * block_q * 4
-    tiles = (block_q + 2 * block_k) * head_dim * 4 + 2 * block_q * block_k * 4
+    probabilities).  ``v_dim`` is the value width (default ``head_dim``)."""
+    dv = head_dim if v_dim is None else v_dim
+    qk = (block_q + block_k) * head_dim
+    vo = (block_k + block_q) * dv
+    blocks = 2 * ((qk + vo) * itemsize + block_q * LANES * 4)
+    scratch = (2 * LANES + dv) * block_q * 4
+    tiles = (qk + block_k * dv) * 4 + 2 * block_q * block_k * 4
     return blocks + scratch + tiles
 
 
-def fwd_block_sizes(q_len: int, kv_len: int, head_dim: int, itemsize: int):
+def fwd_block_sizes(q_len: int, kv_len: int, head_dim: int, itemsize: int,
+                    v_dim: Optional[int] = None):
     """(block_q, block_k) of the forward: of the candidates that divide
     each length, the largest ``block_k`` and then the largest ``block_q``
     whose working set fits ``FWD_VMEM_BUDGET``.  A larger ``block_k``
@@ -114,7 +125,7 @@ def fwd_block_sizes(q_len: int, kv_len: int, head_dim: int, itemsize: int):
 
     for bk in divisors(kv_len):
         for bq in divisors(q_len):
-            if _fwd_vmem_bytes(bq, bk, head_dim, itemsize) <= FWD_VMEM_BUDGET:
+            if _fwd_vmem_bytes(bq, bk, head_dim, itemsize, v_dim) <= FWD_VMEM_BUDGET:
                 return bq, bk
     return min(DEFAULT_BLOCK_Q, q_len), min(DEFAULT_BLOCK_K, kv_len)
 
@@ -141,7 +152,7 @@ def _flash_fwd_kernel(
     def tile(masked: bool):
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, Dh)
         k = k_ref[0, 0].astype(jnp.float32)                  # (bk, Dh)
-        v = v_ref[0, 0].astype(jnp.float32)                  # (bk, Dh)
+        v = v_ref[0, 0].astype(jnp.float32)                  # (bk, Dv)
         s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
         if masked:
             shape = (block_q, block_k)
@@ -274,7 +285,7 @@ def flash_attention_bwd(
     """Blocked backward (dq then dk/dv); GQA handled by summing dk/dv over
     the query-head group outside (kv heads are broadcast in the kernels)."""
     B, H, Sq, Dh = q.shape
-    Hk, Skv = k.shape[1], k.shape[2]
+    Hk, Skv, Dv = k.shape[1], k.shape[2], v.shape[3]
     group = H // Hk
     if scale is None:
         scale = Dh ** -0.5
@@ -294,8 +305,8 @@ def flash_attention_bwd(
         in_specs=[
             pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
-            pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, iq, ik, g=group: (b, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
@@ -315,29 +326,29 @@ def flash_attention_bwd(
         in_specs=[
             pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, ik, iq: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, ik, iq, g=group: (b, h // g, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, ik, iq, g=group: (b, h // g, ik, 0)),
-            pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, ik, iq: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, ik, iq, g=group: (b, h // g, ik, 0)),
+            pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, ik, iq: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, ik, iq: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b, h, ik, iq: (b, h, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, Dh), lambda b, h, ik, iq: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, ik, iq: (b, h, ik, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Skv, Dh), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Skv, Dh), q.dtype),
+            jax.ShapeDtypeStruct((B, H, Skv, Dv), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, Dh), jnp.float32),
-            pltpu.VMEM((block_k, Dh), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     # reduce over the GQA group back to kv heads
     dk = dk_h.reshape(B, Hk, group, Skv, Dh).sum(axis=2).astype(k.dtype)
-    dv = dv_h.reshape(B, Hk, group, Skv, Dh).sum(axis=2).astype(v.dtype)
+    dv = dv_h.reshape(B, Hk, group, Skv, Dv).sum(axis=2).astype(v.dtype)
     return dq, dk, dv
 
 
@@ -346,12 +357,12 @@ def _flash_forward(
     block_q, block_k, interpret: bool,
 ):
     B, H, Sq, Dh = q.shape
-    Hk, Skv = k.shape[1], k.shape[2]
+    Hk, Skv, Dv = k.shape[1], k.shape[2], v.shape[3]
     group = H // Hk
     if scale is None:
         scale = Dh ** -0.5
     if block_q is None or block_k is None:
-        auto_q, auto_k = fwd_block_sizes(Sq, Skv, Dh, q.dtype.itemsize)
+        auto_q, auto_k = fwd_block_sizes(Sq, Skv, Dh, q.dtype.itemsize, Dv)
         block_q = auto_q if block_q is None else block_q
         block_k = auto_k if block_k is None else block_k
     block_q = min(block_q, Sq)
@@ -374,22 +385,23 @@ def _flash_forward(
         q_offset=q_offset, block_q=block_q, block_k=block_k, kv_len=Skv,
     )
     q_spec = pl.BlockSpec((1, 1, block_q, Dh), lambda b, h, iq, ik: (b, h, iq, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, Dh), kv_map)
-    out_specs = [q_spec]
-    out_shape = [jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype)]
+    k_spec = pl.BlockSpec((1, 1, block_k, Dh), kv_map)
+    v_spec = pl.BlockSpec((1, 1, block_k, Dv), kv_map)
+    out_specs = [pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik: (b, h, iq, 0))]
+    out_shape = [jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype)]
     if with_lse:
         out_specs.append(pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)))
         out_shape.append(jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32))
     outs = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, Dh), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         name="flash_fwd_lse" if with_lse else "flash_fwd",
         interpret=interpret,
@@ -422,8 +434,9 @@ def flash_attention_fwd(
     block_k: Optional[int] = None,
     interpret: bool,
 ) -> jax.Array:
-    """q: (B, H, Sq, Dh); k/v: (B, Hk, Skv, Dh) with H % Hk == 0.  Block
-    sizes default to ``fwd_block_sizes``."""
+    """q: (B, H, Sq, Dh); k: (B, Hk, Skv, Dh), v: (B, Hk, Skv, Dv) with
+    H % Hk == 0 -> (B, H, Sq, Dv).  Block sizes default to
+    ``fwd_block_sizes``."""
     return _flash_forward(
         q, k, v, with_lse=False, causal=causal, window=window, scale=scale,
         q_offset=q_offset, block_q=block_q, block_k=block_k, interpret=interpret,

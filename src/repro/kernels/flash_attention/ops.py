@@ -1,7 +1,7 @@
 """jit'd public wrapper for the flash attention kernel.
 
 ``flash_attention`` takes model-layout tensors q (B, S, H, Dh),
-k/v (B, S, Hk, Dh), transposes to kernel layout and runs the Pallas
+k (B, S, Hk, Dh), v (B, S, Hk, Dv), transposes to kernel layout and runs the Pallas
 kernels through a custom_vjp: the forward saves the logsumexp rows and the
 backward runs the blocked dq and dk/dv kernels.  The platform picks the
 mode: interpret on the CPU, compiled on the TPU.
@@ -65,7 +65,8 @@ def flash_attention(
     scale: Optional[float] = None,
     q_offset: int = 0,
 ) -> jax.Array:
-    """Model layout: q (B, S, H, Dh), k/v (B, S, Hk, Dh) -> (B, S, H, Dh)."""
+    """Model layout: q (B, S, H, Dh), k (B, S, Hk, Dh), v (B, S, Hk, Dv)
+    -> (B, S, H, Dv)."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

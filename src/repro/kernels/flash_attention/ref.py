@@ -1,7 +1,8 @@
 """Pure-jnp oracle for the flash attention kernel.
 
 Semantics: causal (optionally sliding-window) GQA attention,
-q (B, H, Sq, Dh), k/v (B, Hk, Skv, Dh), f32 accumulation, output in q.dtype.
+q (B, H, Sq, Dh), k (B, Hk, Skv, Dh), v (B, Hk, Skv, Dv), f32
+accumulation, output (B, H, Sq, Dv) in q.dtype.
 ``q_offset`` places the q block at absolute position q_offset in the kv
 timeline (0 for training/prefill).
 """
@@ -43,4 +44,4 @@ def attention_ref(
     logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v.astype(jnp.float32))
-    return out.reshape(B, H, Sq, Dh).astype(q.dtype)
+    return out.reshape(B, H, Sq, v.shape[-1]).astype(q.dtype)

@@ -196,11 +196,12 @@ def run_cell(
     hlo = compiled.as_text()
     extra_flops = 0.0
     if cfg.attn_impl in ("flash", "flash_stub"):
-        # attention FLOPs live inside the opaque kernel: 2 matmuls x
-        # 2*B*H*S^2*Dh, halved for causal; train = 4x (fwd + remat + bwd).
+        # attention FLOPs live inside the opaque kernel: QK^T and PV,
+        # 2*B*H*S^2 times the query/key and the value width, halved for
+        # causal; train = 4x (fwd + remat + bwd).
         B, S = shape.global_batch, shape.seq_len
-        H, Dh, L = cfg.heads, cfg.resolved_head_dim, cfg.num_layers
-        fwd = 2 * 2 * B * H * S * S * Dh * 0.5 * L
+        H, L = cfg.heads, cfg.num_layers
+        fwd = 2 * B * H * S * S * sum(cfg.attn_widths) * 0.5 * L
         extra_flops = fwd * (4 if shape.kind == "train" else 1)
     report = roofline.build_report(
         arch, shape_name, mesh_name, chips, hlo, ca, _mem_dict(ma),

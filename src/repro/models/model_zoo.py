@@ -45,8 +45,13 @@ class ModelZoo:
 
     def loss(self, params, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """Next-token cross entropy over batch['targets'] with optional
-        batch['loss_mask']; adds MoE aux loss."""
-        logits, aux = self.forward(params, batch)
+        batch['loss_mask']; adds MoE aux loss.  The metrics carry the
+        model's counters where it has them (``forward_with_stats``)."""
+        with_stats = getattr(self._mod, "forward_with_stats", None)
+        if with_stats is not None:
+            logits, aux, stats = with_stats(params, self.cfg, batch)
+        else:
+            (logits, aux), stats = self.forward(params, batch), {}
         with jax.named_scope("head"):
             targets = batch["targets"]
             logits32 = logits.astype(jnp.float32)
@@ -59,7 +64,7 @@ class ModelZoo:
             else:
                 loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         total = loss + aux
-        return total, {"nll": loss, "aux": aux}
+        return total, {"nll": loss, "aux": aux, **stats}
 
 
 _FAMILIES = {

@@ -1,7 +1,16 @@
 """Mixture-of-Experts FFN with expert parallelism over the RailX rail-ring
 all-to-all dimension (paper §3.3.4 / Figure 9 / Table 4 "Expert (E)" row).
 
-Two functionally equivalent implementations:
+Three implementations:
+
+* ``moe_ffn_held`` — one chip's share of an expert-parallel layer, with no
+  token dropped: the router scores every expert, the (token, expert)
+  pairs routed to the experts this chip holds are sorted by expert and
+  go through grouped matrix products over the held experts (Pallas
+  megablox ``gmm`` on the TPU, ``lax.ragged_dot`` elsewhere).  Taken
+  whenever the configuration names the held experts
+  (``MoEConfig.held_experts``); on one chip it runs without the exchange
+  that would bring the other chips' tokens.
 
 * ``moe_ffn_dense`` — scatter/gather capacity dispatch on one device (or
   pure GSPMD).  O(T*K + E*C*D); used for smoke tests and as the oracle.
@@ -11,16 +20,21 @@ Two functionally equivalent implementations:
   (combine).  This is precisely the traffic the paper maps onto rail-ring
   all-to-all, and the collective bytes show up in the dry-run HLO.
 
-Router: softmax top-k with aux load-balancing loss (paper §A.4 Listing 1:
-``aux_loss``, coeff 0.01, alltoall dispatcher).
+Every path takes the router of the configuration (``route_topk``):
+softmax or sigmoid (DeepSeek-V3) scores in f32, top-k, gates normalised
+over the top-k and scaled by ``routed_scale``; the score correction bias
+of DeepSeek-V3's aux-loss-free balancing is held at zero (top-k of the
+scores themselves).  The capacity paths add an aux load-balancing loss
+(paper §A.4 Listing 1: ``aux_loss``, coeff 0.01, alltoall dispatcher);
+the held path has none.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,19 +52,26 @@ class MoEConfig:
     capacity_factor: float = 1.25
     aux_loss_coeff: float = 0.01
     num_shared_experts: int = 0
-    router_dtype: Any = jnp.float32
     ep_axis: str = "data"      # mesh axis carrying expert parallelism
     tp_axis: str = "model"     # mesh axis carrying tensor parallelism
     token_scatter: bool = False  # M4: shard expert queues over TP (see body)
+    scoring: str = "softmax"   # softmax | sigmoid (route_topk)
+    routed_scale: float = 1.0
+    held_experts: Optional[int] = None   # held path: experts on this chip
+    first_held: int = 0
+
+    @property
+    def local_experts(self) -> int:
+        return self.num_experts if self.held_experts is None else self.held_experts
 
 
 def init_moe(key, cfg: MoEConfig, dt: DTypes) -> Params:
     ks = jax.random.split(key, 5)
-    E, D, F = cfg.num_experts, cfg.d_model, cfg.d_ff
+    E, D, F = cfg.local_experts, cfg.d_model, cfg.d_ff
     s_in = 1.0 / math.sqrt(D)
     s_out = 1.0 / math.sqrt(F)
     p: Params = {
-        "router": init_linear(ks[0], D, E, dt),
+        "router": init_linear(ks[0], D, cfg.num_experts, dt),
         "wi": trunc_normal(ks[1], (E, D, F), s_in, dt.param),
         "wg": trunc_normal(ks[2], (E, D, F), s_in, dt.param),
         "wo": trunc_normal(ks[3], (E, F, D), s_out, dt.param),
@@ -77,24 +98,21 @@ def moe_specs(cfg: MoEConfig) -> Params:
 
 
 # ---------------------------------------------------------------------------
-# Routing (shared by both paths; operates on local tokens)
+# Capacity routing (the dense and EP paths; operates on local tokens)
 # ---------------------------------------------------------------------------
 
 
 def _route(
-    p: Params, cfg: MoEConfig, xt: jax.Array, dt: DTypes, capacity: int
+    p: Params, cfg: MoEConfig, xt: jax.Array, capacity: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Returns (src_token (E,C), slot_gate (E,C), slot_valid (E,C), aux,
-    router probs)."""
+    router scores)."""
     T, D = xt.shape
     E, K = cfg.num_experts, cfg.top_k
-    logits = (xt @ dt.c(p["router"]["w"])).astype(cfg.router_dtype)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, K)                   # (T, K)
-    gate_vals = gate_vals / jnp.clip(jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+    gate_vals, gate_idx, probs = route_topk(p["router"]["w"], cfg, xt)   # (T, K)
 
     me = jnp.mean(probs, axis=0)
-    ce = jnp.zeros((E,), cfg.router_dtype).at[gate_idx.reshape(-1)].add(1.0) / (T * K)
+    ce = jnp.zeros((E,), jnp.float32).at[gate_idx.reshape(-1)].add(1.0) / (T * K)
     aux = cfg.aux_loss_coeff * E * jnp.sum(me * ce)
 
     # position-in-expert via stable sort (O(TK log TK), ~MB-scale buffers)
@@ -146,7 +164,7 @@ def moe_ffn_dense(
     T = B * S
     xt = x.reshape(T, D)
     capacity = int(max(1, round(cfg.capacity_factor * T * cfg.top_k / cfg.num_experts)))
-    src_token, slot_gate, slot_valid, aux, _ = _route(p, cfg, xt, dt, capacity)
+    src_token, slot_gate, slot_valid, aux, _ = _route(p, cfg, xt, capacity)
     expert_in = xt[src_token] * slot_valid[..., None].astype(xt.dtype)  # (E,C,D)
     expert_out = _expert_ffn(p, expert_in, dt)
     weighted = expert_out * (slot_gate * slot_valid)[..., None].astype(xt.dtype)
@@ -198,7 +216,7 @@ def moe_ffn_ep(
         if has_tp:
             capacity = ((capacity + tp - 1) // tp) * tp
         src_token, slot_gate, slot_valid, aux, _ = _route(
-            {"router": {"w": router_w}}, cfg, xt, dt, capacity
+            {"router": {"w": router_w}}, cfg, xt, capacity
         )
         expert_in = xt[src_token] * slot_valid[..., None].astype(xt.dtype)
         if has_tp and cfg.token_scatter:
@@ -266,10 +284,153 @@ def moe_ffn_ep(
     return out, aux.astype(jnp.float32)
 
 
+# ---------------------------------------------------------------------------
+# Held path: one chip's experts, dropless (grouped matrix products)
+# ---------------------------------------------------------------------------
+
+
+def route_topk(router_w: jax.Array, cfg: MoEConfig, xt: jax.Array):
+    """(gates (T, K) f32, expert ids (T, K), scores (T, E) f32) over all
+    ``num_experts``: scores in f32 at full precision, top-k, gates
+    normalised over the top-k and scaled by ``routed_scale``."""
+    logits = jnp.dot(xt.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if cfg.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif cfg.scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {cfg.scoring!r}")
+    gates, idx = jax.lax.top_k(scores, cfg.top_k)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates * cfg.routed_scale, idx, scores
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inv, k):
+    """Rows of x (T, D) for the (token, expert) pairs in ``order`` (T*k,),
+    pair p being token p // k.  The gradient gathers too: each token sums
+    its k pairs' rows (``inv`` is the inverse permutation)."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv, k):
+    return x[order // k], (order, inv)
+
+
+def _dispatch_bwd(k, res, g):
+    order, inv = res
+    gt = g[inv].astype(jnp.float32)
+    return gt.reshape(-1, k, g.shape[-1]).sum(axis=1).astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(y, idx, inv):
+    """y[idx] for a permutation ``idx`` whose inverse is ``inv``; its
+    gradient is the gather g[inv], not a scatter."""
+    return y[idx]
+
+
+def _permute_fwd(y, idx, inv):
+    return y[idx], (idx, inv)
+
+
+def _permute_bwd(res, g):
+    _, inv = res
+    return g[inv], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """megablox tiles (tm, tk, tn), for ``gmm`` (tm x tk by tk x tn into a
+    tm x tn accumulator) and ``tgmm`` (tk x tm by tm x tn into tk x tn):
+    512 rows, a width up to 1,536 whole and a larger one in 1,024s; then
+    the largest tile that halves into a divisor of its dimension is halved
+    while the double-buffered bf16 blocks and the f32 accumulator of
+    either kernel exceed 12 MiB of VMEM (the limit is 16)."""
+    def vmem(tm, tk, tn):
+        acc = max(tm, tk) * tn
+        return 2 * 2 * (tm * tk + tk * tn + acc) + 4 * acc
+
+    tiles = {"m": min(512, m), "k": k if k <= 1536 else 1024, "n": n if n <= 1536 else 1024}
+    dims = {"m": m, "k": k, "n": n}
+    while vmem(tiles["m"], tiles["k"], tiles["n"]) > 12 * 2**20:
+        halvable = [d for d in ("n", "k", "m")
+                    if tiles[d] % 256 == 0 and dims[d] % (tiles[d] // 2) == 0]
+        if not halvable:
+            break
+        d = max(halvable, key=lambda d: tiles[d])
+        tiles[d] //= 2
+    return tiles["m"], tiles["k"], tiles["n"]
+
+
+def _on_cpu() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+def _grouped(x: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
+    """Row group g of x (sizes[g] consecutive rows, g < len(w)) times
+    w[g]; rows past the groups (``sizes`` has one more entry, for them)
+    come out zero."""
+    if not _on_cpu():
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        return gmm(x, w, sizes, x.dtype, _gmm_tiling)
+    return jax.lax.ragged_dot(x, w, sizes[:-1]).astype(x.dtype)
+
+
+def moe_ffn_held(
+    p: Params, cfg: MoEConfig, x: jax.Array, dt: DTypes
+) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """One chip's share of the layer: the routed experts' part of the
+    result from the experts [first_held, first_held + held_experts), for
+    every token routed to them, plus the shared expert.  Returns (out, aux
+    (zero), counters): ``moe_tokens_held`` the (token, expert) pairs routed
+    to held experts, ``moe_max_load`` the busiest held expert's pairs over
+    the held experts' mean."""
+    B, S, D = x.shape
+    T, K, Eh = B * S, cfg.top_k, cfg.held_experts
+    xt = x.reshape(T, D)
+    with jax.named_scope("moe_route"):
+        gates, idx, _ = route_topk(p["router"]["w"], cfg, xt)
+        local = idx.reshape(-1) - cfg.first_held
+        group = jnp.where((local >= 0) & (local < Eh), local, Eh)   # Eh: not held
+        order = jnp.argsort(group, stable=True)
+        inv = jnp.argsort(order)
+        sizes = jnp.bincount(group, length=Eh + 1).astype(jnp.int32)
+        held = T * K - sizes[Eh]
+    with jax.named_scope("moe_experts"):
+        # pairs of experts not held sort last; their rows come out zero
+        xs = _dispatch(xt, order, inv, K)
+        h = jax.nn.silu(_grouped(xs, dt.c(p["wg"]), sizes)) * _grouped(xs, dt.c(p["wi"]), sizes)
+        y = _grouped(h, dt.c(p["wo"]), sizes)
+        y = _permute(y, inv, order).reshape(T, K, D).astype(jnp.float32)
+        out = jnp.sum(y * gates[..., None], axis=1).astype(x.dtype).reshape(B, S, D)
+    if cfg.num_shared_experts:
+        from .common import swiglu
+
+        with jax.named_scope("moe_shared"):
+            out = out + swiglu(p["shared"], x, dt)
+    load = sizes[:Eh].astype(jnp.float32)
+    mean = jnp.mean(load)
+    stats = {"moe_tokens_held": held,
+             "moe_max_load": jnp.where(mean > 0, jnp.max(load) / jnp.maximum(mean, 1e-9), 0.0)}
+    return out, jnp.zeros((), jnp.float32), stats
+
+
 def moe_ffn(
     p: Params, cfg: MoEConfig, x: jax.Array, dt: DTypes, impl: str = "auto"
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """(out, aux loss, counters); the counters are the held path's, empty
+    for the capacity paths."""
+    if cfg.held_experts is not None:
+        return moe_ffn_held(p, cfg, x, dt)
     mesh = current_mesh()
     if impl == "ep" or (impl == "auto" and mesh is not None and cfg.ep_axis in getattr(mesh, "shape", {})):
-        return moe_ffn_ep(p, cfg, x, dt, mesh)
-    return moe_ffn_dense(p, cfg, x, dt)
+        return (*moe_ffn_ep(p, cfg, x, dt, mesh), {})
+    return (*moe_ffn_dense(p, cfg, x, dt), {})

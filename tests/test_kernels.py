@@ -146,6 +146,37 @@ def test_flash_attention_property(S, Dh, causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+@pytest.mark.parametrize("B,H,Hk,S,Dqk,Dv,causal", [
+    (1, 4, 4, 256, 192, 128, True),     # latent attention's widths (Moonlight)
+    (2, 4, 2, 128, 48, 32, True),
+    (1, 2, 1, 256, 64, 128, False),
+])
+def test_flash_attention_value_width_differs(B, H, Hk, S, Dqk, Dv, causal):
+    """Queries and keys of one width, values of another: the forward and
+    all three gradients against the oracle (interpret mode)."""
+    ks = jax.random.split(jax.random.PRNGKey(S + Dqk), 4)
+    q = jax.random.normal(ks[0], (B, S, H, Dqk), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, Hk, Dqk), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, Hk, Dv), jnp.float32)
+    w = jax.random.normal(ks[3], (B, S, H, Dv), jnp.float32)
+    scale = Dqk ** -0.5
+
+    def ref(q, k, v):
+        t = lambda x: jnp.swapaxes(x, 1, 2)
+        return t(attention_ref(t(q), t(k), t(v), causal=causal, scale=scale))
+
+    out = flash_attention(q, k, v, causal=causal, scale=scale)
+    assert out.shape == (B, S, H, Dv)
+    np.testing.assert_allclose(out, ref(q, k, v), atol=2e-5, rtol=2e-5)
+    loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) * w)
+    got = jax.grad(loss(lambda q, k, v: flash_attention(q, k, v, causal=causal, scale=scale)),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(g, r, atol=1e-4, rtol=1e-4)
+
+
 def test_flash_attention_grad_via_ref():
     q = jnp.array(RNG.randn(1, 64, 2, 32), jnp.float32)
     k = jnp.array(RNG.randn(1, 64, 2, 32), jnp.float32)

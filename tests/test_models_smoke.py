@@ -36,8 +36,11 @@ def test_forward_and_loss(arch):
     assert not bool(jnp.isnan(logits).any())
     loss, metrics = zoo.loss(params, batch)
     assert jnp.isfinite(loss)
-    if cfg.moe is not None:
+    if cfg.moe is not None and cfg.moe.aux_loss_coeff > 0:
         assert float(aux) > 0.0  # aux loss active
+    if cfg.moe is not None and cfg.moe.held_experts is not None:
+        # a held share counts the pairs routed to it
+        assert 0 < int(metrics["moe_tokens_held"]) <= (cfg.num_layers - cfg.first_dense_layers) * 32 * cfg.moe.top_k
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -46,6 +49,13 @@ def test_decode_steps(arch):
     zoo = get_model(cfg)
     params = zoo.init(jax.random.PRNGKey(0))
     B = 2
+    if cfg.mla is not None:
+        # the latent cache is not implemented: decoding refuses, plainly
+        with pytest.raises(NotImplementedError, match="latent"):
+            zoo.init_cache(B, 32)
+        with pytest.raises(NotImplementedError, match="latent"):
+            zoo.decode_step(params, {}, {"tokens": jnp.zeros((B, 1), jnp.int32)})
+        return
     cache = zoo.init_cache(B, 32)
     if cfg.family == "whisper":
         cache["enc_out"] = jax.random.normal(
@@ -101,3 +111,45 @@ def test_param_counts_documented():
         actual = sum(np.prod(p.shape) for p in jax.tree_util.tree_leaves(params))
         est = cfg.param_count()
         assert abs(actual - est) / actual < 0.25, (arch, actual, est)
+
+
+@pytest.mark.parametrize("impl", ["dense", "held"])
+def test_moonlight_router_on_every_path(impl):
+    """The published configuration's router (64 experts, sigmoid scores,
+    top-6, gates normalised over the top-6 and scaled by 2.446, 2 shared
+    experts), at a width a CPU holds, on the capacity path (capacity
+    wide enough that no token drops) and on the held path holding every
+    expert: both give the layer computed by hand from those gates."""
+    import dataclasses
+
+    import numpy as np
+
+    from repro.configs import get_config
+    from repro.models.common import DTypes, swiglu
+    from repro.models.moe import init_moe, moe_ffn
+    from repro.models.transformer import _moe_cfg
+
+    full = get_config("moonlight-16b-a3b")
+    assert (full.moe.num_experts, full.moe.top_k, full.moe.scoring, full.moe.routed_scale,
+            full.moe.num_shared_experts, full.moe.held_experts) == (64, 6, "sigmoid", 2.446, 2, None)
+    moe = dataclasses.replace(full.moe, d_ff=16, capacity_factor=64 / 6,
+                              held_experts=64 if impl == "held" else None)
+    cfg = _moe_cfg(dataclasses.replace(full, d_model=32, moe=moe))
+    dt = DTypes(jnp.float32, jnp.float32)
+    p = init_moe(jax.random.PRNGKey(0), cfg, dt)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 32))
+    out, _, _ = moe_ffn(p, cfg, x, dt, impl="dense")
+
+    xt = np.asarray(x.reshape(-1, 32), np.float64)
+    s = 1 / (1 + np.exp(-xt @ np.asarray(p["router"]["w"], np.float64)))
+    top = np.argsort(-s, axis=-1)[:, :6]
+    gates = np.take_along_axis(s, top, -1)
+    gates = 2.446 * gates / gates.sum(-1, keepdims=True)
+    w = {k: np.asarray(p[k], np.float64) for k in ("wi", "wg", "wo")}
+    want = np.zeros_like(xt)
+    for t in range(xt.shape[0]):
+        for e, g in zip(top[t], gates[t]):
+            h = xt[t] @ w["wg"][e]
+            want[t] += g * ((h / (1 + np.exp(-h))) * (xt[t] @ w["wi"][e])) @ w["wo"][e]
+    want += np.asarray(swiglu(p["shared"], x.reshape(-1, 32), dt), np.float64)
+    np.testing.assert_allclose(np.asarray(out).reshape(-1, 32), want, atol=1e-4, rtol=1e-4)

@@ -209,3 +209,117 @@ def test_decode_cache_write_is_under_kv_cache(compiled_steps):
 def test_train_step_has_each_layer_scope(compiled_steps):
     found = {scopes.scope_of(ins.op_name) for ins in scopes.instructions(compiled_steps["train"])}
     assert {"embed", "layers", "attention", "mlp", "head", "optimizer"} <= found
+
+
+# ---------------------------------------------------------------------------
+# moonlight-16b-a3b: latent attention's widths and the held experts
+# ---------------------------------------------------------------------------
+
+# the benchmark cell's shapes: 2 x 8,192 tokens, 16 heads, queries and keys
+# of 192, values of 128; 8 held experts of 1,408 over d_model 2,048, the
+# (token, expert) pairs of top-6 routing (98,304 rows) in 9 groups (the
+# last holds the pairs of experts that are not held)
+MLA_B, MLA_H, MLA_S, MLA_DQK, MLA_DV = 2, 16, 8192, 192, 128
+
+
+@pytest.mark.parametrize("entry", ["fwd_lse", "bwd"])
+def test_flash_compiles_at_latent_attention_widths(entry, one_chip, no_persistent_cache):
+    q = _sds((MLA_B, MLA_H, MLA_S, MLA_DQK), jnp.bfloat16, one_chip)
+    v = _sds((MLA_B, MLA_H, MLA_S, MLA_DV), jnp.bfloat16, one_chip)
+    lse = _sds((MLA_B, MLA_H, MLA_S, 1), jnp.float32, one_chip)
+    if entry == "fwd_lse":
+        n = _kernel_calls(lambda q, k, v: flash_attention_fwd_lse(q, k, v, interpret=False), q, q, v)
+        assert n >= 1
+    else:
+        n = _kernel_calls(
+            lambda q, k, v, o, lse, do: flash_attention_bwd(q, k, v, o, lse, do, interpret=False),
+            q, q, v, v, lse, v,
+        )
+        assert n >= 2
+
+
+def test_held_expert_products_compile(one_chip, no_persistent_cache):
+    """The grouped products of one MoE layer, forward and backward
+    (``gmm`` and ``tgmm``), at the tiles ``_gmm_tiling`` picks."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from repro.models.moe import _gmm_tiling
+
+    x = _sds((98304, 2048), jnp.bfloat16, one_chip)
+    w = _sds((8, 2048, 1408), jnp.bfloat16, one_chip)
+    wo = _sds((8, 1408, 2048), jnp.bfloat16, one_chip)
+    sizes = _sds((9,), jnp.int32, one_chip)
+
+    def f(x, w, wo, sizes):
+        h = gmm(x, w, sizes, jnp.bfloat16, _gmm_tiling)
+        return jnp.sum(jnp.sin(gmm(h, wo, sizes, jnp.bfloat16, _gmm_tiling).astype(jnp.float32)))
+
+    n = _kernel_calls(jax.grad(f, argnums=(0, 1, 2)), x, w, wo, sizes)
+    assert n >= 6   # two forward products; each backward: gmm and tgmm
+
+
+TINY_MLA = dict(name="tiny-mla", family="moe", num_layers=2, d_model=256, heads=2, kv_heads=2,
+                d_ff=512, vocab=512, tie_embeddings=False, rms_norm_eps=1e-5,
+                first_dense_layers=1,
+                mla={"kv_lora_rank": 128, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+                     "v_head_dim": 128},
+                moe={"num_experts": 8, "top_k": 2, "d_ff": 256, "num_shared_experts": 1,
+                     "scoring": "sigmoid", "routed_scale": 2.446, "held_experts": 4,
+                     "aux_loss_coeff": 0.0},
+                param_dtype=jnp.float32, compute_dtype=jnp.bfloat16, remat=True, attn_impl="flash")
+
+
+@pytest.fixture(scope="module")
+def compiled_moe_step(topo, no_persistent_cache):
+    """The compiled HLO text of a tiny latent-attention, held-expert
+    model's train step for one described chip, kernels compiled."""
+    from repro.configs.base import ModelConfig
+    from repro.kernels.flash_attention import ops
+    from repro.models import moe
+    from repro.models.model_zoo import get_model
+    from repro.train import optimizer as opt_lib
+    from repro.train.train_step import make_train_step
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    zoo = get_model(ModelConfig(**TINY_MLA))
+    ocfg = opt_lib.AdamWConfig()
+    params = jax.eval_shape(lambda: zoo.init(jax.random.PRNGKey(0)))
+    example = {"tokens": np.zeros((TB, TS), np.int32), "targets": np.zeros((TB, TS), np.int32)}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_on_cpu", lambda: False)
+        mp.setattr(moe, "_on_cpu", lambda: False)
+        arts = make_train_step(zoo, ocfg, mesh, example)
+        batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=arts.batch_sharding[k])
+                 for k, v in example.items()}
+        return arts.step_fn.lower(
+            _shapes(params, arts.param_sharding),
+            _shapes(jax.eval_shape(lambda p: opt_lib.init(ocfg, p), params), arts.opt_sharding),
+            batch).compile().as_text()
+
+
+def _sub_scopes(op_name: str) -> set:
+    return {scopes._unwrap(p) for p in op_name.split("/")}
+
+
+def test_moe_step_kernels_and_sub_scopes(compiled_moe_step):
+    """The flash kernels sit under ``attention`` and the grouped products
+    under ``moe``/``moe_experts``; the step has the sub-scopes the
+    benchmark's readers take (``moe_route``, ``moe_experts``,
+    ``moe_shared``, ``mla_kv``), and they count under their scope."""
+    kernels, subs = set(), {}
+    for ins in scopes.instructions(compiled_moe_step):
+        parts = _sub_scopes(ins.op_name)
+        for sub in ("moe_route", "moe_experts", "moe_shared", "mla_kv"):
+            if sub in parts:
+                subs.setdefault(sub, set()).add(scopes.scope_of(ins.op_name))
+        if 'custom_call_target="tpu_custom_call"' not in ins.text:
+            continue
+        name = scopes.kernel_of(ins.op_name)
+        kernels.add(name)
+        want = "attention" if name.startswith("flash_") else "moe"
+        assert scopes.scope_of(ins.op_name) == want, ins.op_name
+        if want == "moe":
+            assert "moe_experts" in parts, ins.op_name
+    assert {"flash_fwd_lse", "flash_bwd_dq", "flash_bwd_dkv", "gmm", "tgmm"} <= kernels
+    assert subs == {"moe_route": {"moe"}, "moe_experts": {"moe"}, "moe_shared": {"moe"},
+                    "mla_kv": {"attention"}}
