@@ -8,9 +8,14 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention.flash_attention import (
+    VMEM_BUDGET,
+    _bwd_vmem_bytes,
+    bwd_block_sizes,
+    flash_attention_bwd,
     flash_attention_fwd,
     flash_attention_fwd_lse,
     kv_block_range,
+    q_block_range,
     visited_share,
 )
 from repro.kernels.flash_attention.ops import flash_attention
@@ -89,6 +94,63 @@ def test_flash_attention_sweep(
 
 
 @pytest.mark.parametrize(
+    "B,H,Hk,Sq,Skv,q_offset,Dh,Dv,causal,window,blocks,dtype",
+    [
+        (2, 4, 2, 256, 256, 0, 64, 64, True, None, None, jnp.float32),
+        # window smaller than one block, and larger than a block
+        (1, 2, 2, 256, 256, 0, 64, 64, True, 32, (64, 64), jnp.float32),
+        (1, 2, 2, 256, 256, 0, 64, 64, True, 96, (64, 32), jnp.float32),
+        # the last row that sees a k-block opens the next q-block
+        (1, 2, 2, 256, 256, 0, 64, 64, True, 66, (64, 64), jnp.float32),
+        # Sq != Skv at a static offset, GQA group 4, block_q != block_k
+        (1, 4, 1, 128, 512, 384, 64, 64, True, None, (64, 128), jnp.float32),
+        # offsets off the block grid; keys past the last query (no query
+        # sees them: their dk/dv rows are zero)
+        (1, 4, 4, 128, 384, 97, 64, 64, True, 96, (32, 64), jnp.float32),
+        (1, 2, 2, 128, 256, 94, 64, 64, True, None, (32, 64), jnp.float32),
+        # keys before the first query's window
+        (1, 2, 2, 64, 256, 192, 64, 64, True, 32, (32, 32), jnp.float32),
+        # no causal mask: every tile runs; with a window, a lower bound only
+        (2, 2, 2, 256, 256, 0, 64, 64, False, None, (128, 64), jnp.float32),
+        (1, 2, 2, 256, 256, 0, 64, 64, False, 63, (64, 64), jnp.float32),
+        (1, 8, 2, 256, 256, 0, 64, 64, True, None, (64, 128), jnp.bfloat16),
+        (2, 4, 4, 256, 256, 0, 64, 64, True, None, None, jnp.bfloat16),
+        # latent attention's widths: queries and keys 192, values 128
+        (1, 4, 4, 256, 256, 0, 192, 128, True, None, None, jnp.float32),
+    ],
+)
+def test_flash_attention_bwd_sweep(
+    B, H, Hk, Sq, Skv, q_offset, Dh, Dv, causal, window, blocks, dtype
+):
+    """dq, dk and dv of ``flash_attention_bwd`` against the gradients of
+    the oracle (interpret mode)."""
+    q = jnp.array(RNG.randn(B, H, Sq, Dh), dtype)
+    k = jnp.array(RNG.randn(B, Hk, Skv, Dh), dtype)
+    v = jnp.array(RNG.randn(B, Hk, Skv, Dv), dtype)
+    do = jnp.array(RNG.randn(B, H, Sq, Dv), dtype)
+    kw = dict(causal=causal, window=window, q_offset=q_offset)
+    o, lse = flash_attention_fwd_lse(q, k, v, interpret=True, **kw)
+    bkw = {} if blocks is None else dict(block_q=blocks[0], block_k=blocks[1])
+    got = flash_attention_bwd(q, k, v, o, lse, do, interpret=True, **kw, **bkw)
+    f32 = lambda x: x.astype(jnp.float32)
+    _, vjp = jax.vjp(lambda q, k, v: attention_ref(q, k, v, **kw), f32(q), f32(k), f32(v))
+    want = vjp(f32(do))
+    tol = 1e-4 if dtype == jnp.float32 else 3e-2
+    for name, g, r, x in zip("qkv", got, want, (q, k, v)):
+        assert g.shape == x.shape and g.dtype == x.dtype, name
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        np.testing.assert_allclose(g, r, atol=tol * np.abs(r).max(), err_msg=name)
+    kpos = np.arange(Skv)
+    seen = np.ones(Skv, bool)
+    if causal:
+        seen &= kpos <= q_offset + Sq - 1
+    if window is not None:
+        seen &= kpos > q_offset - window
+    for g in got[1:]:
+        assert not np.asarray(g)[:, :, ~seen].any()
+
+
+@pytest.mark.parametrize(
     "Sq,Skv,q_offset,block_q,block_k,causal",
     [
         (64, 64, 0, 16, 16, True),
@@ -121,6 +183,41 @@ def test_kv_block_range_matches_the_mask(Sq, Skv, q_offset, block_q, block_k, ca
                 assert bool(tile.any()) == (int(lo) <= ik <= int(hi)), (window, iq, ik)
 
 
+@pytest.mark.parametrize(
+    "Sq,Skv,q_offset,block_q,block_k,causal",
+    [
+        (64, 64, 0, 16, 16, True),
+        (64, 64, 0, 32, 8, True),
+        (64, 64, 0, 8, 32, True),
+        (32, 96, 41, 8, 16, True),
+        (32, 96, 64, 16, 8, True),
+        (32, 128, 40, 16, 16, True),
+        (64, 64, 0, 16, 8, False),
+        (48, 48, 0, 16, 16, False),
+    ],
+)
+def test_q_block_range_matches_the_mask(Sq, Skv, q_offset, block_q, block_k, causal):
+    """For every window up to twice a block: a tile outside the range has
+    no unmasked (query, key) pair, every tile inside it has at least one,
+    and a k-block no query sees has an empty range."""
+    qpos = np.arange(Sq)[:, None] + q_offset
+    kpos = np.arange(Skv)[None, :]
+    for window in [None, *range(1, 2 * max(block_q, block_k) + 3)]:
+        mask = np.ones((Sq, Skv), bool)
+        if causal:
+            mask &= kpos <= qpos
+        if window is not None:
+            mask &= kpos > qpos - window
+        for ik in range(Skv // block_k):
+            lo, hi = q_block_range(
+                ik, causal=causal, window=window, q_offset=q_offset,
+                block_q=block_q, block_k=block_k, q_len=Sq,
+            )
+            for iq in range(Sq // block_q):
+                tile = mask[iq * block_q:(iq + 1) * block_q, ik * block_k:(ik + 1) * block_k]
+                assert bool(tile.any()) == (int(lo) <= iq <= int(hi)), (window, iq, ik)
+
+
 @pytest.mark.parametrize("block,share", [(128, 0.515625), (256, 0.53125), (512, 0.5625)])
 def test_visited_share_at_4k(block, share):
     """Causal self-attention at S 4,096: the share of tiles the forward runs."""
@@ -129,6 +226,33 @@ def test_visited_share_at_4k(block, share):
         block_q=block, block_k=block,
     )
     assert got == share
+
+
+@pytest.mark.parametrize("kernel,blocks,share", [
+    ("dq", (1024, 512), 0.625),
+    ("dkv", (512, 1024), 0.625),
+    ("dkv", (512, 512), 0.5625),
+    ("dkv", (128, 128), 0.515625),
+])
+def test_bwd_visited_share_at_4k(kernel, blocks, share):
+    """Causal self-attention at S 4,096: the share of tiles each backward
+    kernel runs; at equal blocks it is the forward's."""
+    got = visited_share(
+        q_len=4096, kv_len=4096, causal=True, window=None, q_offset=0,
+        block_q=blocks[0], block_k=blocks[1], kernel=kernel,
+    )
+    assert got == share
+
+
+# (q_len, kv_len, head_dim, v_dim): the qwen3-8b.train-4k and
+# moonlight-16b-a3b.train-8k benchmark cells' attention, bf16
+@pytest.mark.parametrize("shape", [(4096, 4096, 128, 128), (8192, 8192, 192, 128)])
+def test_bwd_block_sizes_divide_and_fit(shape):
+    q_len, kv_len, head_dim, v_dim = shape
+    for kernel, (bq, bk) in zip(("dq", "dkv"), bwd_block_sizes(q_len, kv_len, head_dim, 2, v_dim)):
+        assert q_len % bq == 0 and kv_len % bk == 0
+        assert bq >= 128 and bk >= 128
+        assert _bwd_vmem_bytes(bq, bk, head_dim, 2, v_dim, kernel=kernel) <= VMEM_BUDGET
 
 
 @given(
