@@ -80,7 +80,7 @@ class ModelConfig:
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.float32
     remat: bool = False
-    attn_impl: str = "ref"                  # ref | flash | flash_stub
+    attn_impl: str = "ref"                  # ref | flash
 
     def __post_init__(self):
         if isinstance(self.moe, dict):

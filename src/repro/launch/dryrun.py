@@ -195,7 +195,7 @@ def run_cell(
         ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     extra_flops = 0.0
-    if cfg.attn_impl in ("flash", "flash_stub"):
+    if cfg.attn_impl == "flash":
         # attention FLOPs live inside the opaque kernel: QK^T and PV,
         # 2*B*H*S^2 times the query/key and the value width, halved for
         # causal; train = 4x (fwd + remat + bwd).
@@ -240,7 +240,7 @@ def main() -> None:
     ap.add_argument("--dp-mode", default="gspmd_fsdp")
     ap.add_argument("--schedule", default="hierarchical")
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--attn-impl", default="ref")
+    ap.add_argument("--attn-impl", default="ref", help="ref|flash")
     ap.add_argument("--tag", default="")
     ap.add_argument("--out", default=RESULTS_DIR)
     args = ap.parse_args()

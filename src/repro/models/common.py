@@ -9,6 +9,11 @@ Conventions:
   * weights stored (in_dim, out_dim); y = x @ w
   * attention heads: q heads H, kv heads Hk (GQA), head_dim Dh
   * dtype policy via ``DTypes(param, compute)``
+
+``attention`` is the models' one dense attention layer and ``sdpa`` their
+one attention core: every masked softmax over attention logits under
+``models/`` is ``sdpa``'s, and ``sdpa`` is the one place where the flash
+kernel is chosen.
 """
 
 from __future__ import annotations
@@ -19,8 +24,9 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from ..parallel.sharding import shard_hint
+from ..parallel.sharding import _manual_axes_in_context, current_mesh, shard_hint
 
 Params = Dict[str, Any]
 
@@ -204,53 +210,95 @@ def attention_specs(cfg: AttnConfig) -> Params:
     return p
 
 
-def _attn_mask(
-    q_len: int, kv_len: int, causal: bool, window: Optional[int],
-    q_offset: jax.Array | int = 0,
-) -> jax.Array:
-    """(q_len, kv_len) boolean mask; q positions are offset by q_offset in
-    the kv timeline (decode: q_offset = cache length so far)."""
-    qpos = jnp.arange(q_len)[:, None] + q_offset
-    kpos = jnp.arange(kv_len)[None, :]
-    mask = jnp.ones((q_len, kv_len), bool)
-    if causal:
-        mask &= kpos <= qpos
-    if window is not None:
-        mask &= kpos > qpos - window
-    return mask
-
-
 def sdpa(
-    q: jax.Array, k: jax.Array, v: jax.Array,
+    q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool, window: Optional[int], scale: float,
     q_offset: jax.Array | int = 0,
+    is_global: Optional[jax.Array] = None,
     impl: str = "ref",
 ) -> jax.Array:
-    """Scaled dot-product attention with GQA.
+    """Scaled dot-product attention with GQA: the models' one attention
+    core, and the one place the flash kernel is chosen.
 
-    q: (B, Sq, H, Dh); k/v: (B, Skv, Hk, Dh).  ``impl`` selects the Pallas
-    flash kernel ("pallas") or the jnp reference ("ref"); both share the
-    oracle in kernels/flash_attention/ref.py.
+    q: (B, Sq, H, Dqk); k: (B, Skv, Hk, Dqk); v: (B, Skv, Hk, Dv) ->
+    (B, Sq, H, Dv).  Query i sits at position ``q_offset + i`` of the K/V
+    timeline (decode over a cache filled up to ``q_offset``; later cache
+    positions are masked).  The mask is causal & (window | ``is_global``):
+    a traced per-layer ``is_global`` switches the sliding window off
+    (gemma-style local:global layers inside one scan).
+
+    ``impl="ref"`` is the jnp body, f32 logits and softmax; the kernels'
+    oracle is kernels/flash_attention/ref.py.  ``impl="flash"`` runs the
+    Pallas kernels (``_flash_attention``) for training and prefill
+    (``q_offset`` 0); they have no path for a window.
     """
-    if impl == "pallas":
-        from ..kernels.flash_attention.ops import flash_attention
-
-        return flash_attention(
-            q, k, v, causal=causal, window=window, scale=scale, q_offset=q_offset
-        )
+    if impl == "flash":
+        if window is not None:
+            # the kernels are called without a window: a layer's window may
+            # be switched by a traced flag, which their static mask cannot follow
+            raise ValueError(
+                f"attn_impl={impl!r} has no path for sliding-window layers "
+                f"(window={window}); use attn_impl='ref'"
+            )
+        return _flash_attention(q, k, v, causal, scale)
+    if impl != "ref":
+        raise ValueError(f"unknown attention impl {impl!r}; use 'ref' or 'flash'")
     B, Sq, H, Dh = q.shape
-    Hk = k.shape[2]
+    Skv, Hk = k.shape[1], k.shape[2]
     group = H // Hk
     qf = q.astype(jnp.float32) * scale
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
     qg = qf.reshape(B, Sq, Hk, group, Dh)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kf)
-    mask = _attn_mask(Sq, k.shape[1], causal, window, q_offset)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32))
+    qpos = jnp.arange(Sq)[:, None] + q_offset
+    kpos = jnp.arange(Skv)[None, :]
+    mask = kpos <= qpos if causal else jnp.ones((Sq, Skv), bool)
+    if window is not None:
+        in_window = kpos > qpos - window
+        mask = mask & (in_window if is_global is None else in_window | is_global)
     logits = jnp.where(mask[None, None, None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, vf)
-    return out.reshape(B, Sq, H, Dh).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
+    return out.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
+
+
+def _flash_attention(q, k, v, causal, scale):
+    """Flash attention in model layout, placed per shard so that scores
+    never materialize in HBM.
+
+    * No mesh in context: the kernel is called directly.
+    * Under a mesh: a shard_map island with batch over the DP axes and
+      heads over the TP axis.  Inside a manual region (the ``manual_hier``
+      trainer) the arrays are already per-shard on the manual axes, so the
+      island spans only the remaining auto axes, and the kernel is called
+      directly when none remain.
+
+    GQA KV heads are broadcast to the query heads before an island so the
+    head dim shards cleanly (the kernels reduce dk/dv back over the group)."""
+    from ..kernels.flash_attention.ops import flash_attention
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=causal, scale=scale)
+
+    mesh = current_mesh()
+    manual = _manual_axes_in_context() or set()
+    auto = [a for a in mesh.axis_names if a not in manual] if mesh is not None else []
+    if not auto:
+        return kernel(q, k, v)
+    B, S, H, Dh = q.shape
+    group = H // k.shape[2]
+    if group > 1:
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
+    dp = tuple(a for a in ("pod", "data") if a in auto)
+    tp = "model" if "model" in auto else None
+    bspec = dp if (dp and B % math.prod(mesh.shape[a] for a in dp) == 0) else None
+    hspec = tp if (tp and H % mesh.shape[tp] == 0) else None
+    spec = P(bspec, None, hspec, None)
+    return jax.shard_map(
+        kernel, mesh=jax.sharding.get_abstract_mesh() if manual else mesh,
+        in_specs=(spec, spec, spec), out_specs=spec,
+        axis_names=set(auto), check_vma=False,
+    )(q, k, v)
 
 
 def attention(
@@ -263,13 +311,19 @@ def attention(
     cache_index: Optional[jax.Array] = None,
     positions3: Optional[jax.Array] = None,
     xattn_kv: Optional[jax.Array] = None,
+    is_global: Optional[jax.Array] = None,
     impl: str = "ref",
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
-    """Returns (output, updated_kv_cache).
+    """The models' one dense attention layer: projections, qk-norm, RoPE
+    (M-RoPE where ``positions3`` is given), ``sdpa``, output projection.
+    Returns (output, updated_kv_cache).
 
-    * training/prefill: kv_cache=None -> attends within x.
-    * decode: kv_cache=(k, v) (B, S_max, Hk, Dh), cache_index = filled len.
+    * training/prefill: kv_cache=None -> attends within x at ``impl``.
+    * decode: kv_cache=(k, v) (B, S_max, Hk, Dh), cache_index = filled len;
+      the jnp body attends over the whole cache, whatever ``impl`` says.
     * cross-attention: xattn_kv = encoder states (keys/values from there).
+
+    ``is_global`` (a traced per-layer flag) switches ``cfg.window`` off.
     """
     B, S, D = x.shape
     H, Hk, Dh = cfg.heads, cfg.kv_heads, cfg.head_dim
@@ -284,59 +338,28 @@ def attention(
         q = rmsnorm(p["q_norm"], q)
         k = rmsnorm(p["k_norm"], k)
     if xattn_kv is None:
-        if cfg.mrope_sections is not None:
-            assert positions3 is not None
+        if cfg.mrope_sections is not None and positions3 is not None:
             q = apply_mrope(q, positions3, cfg.mrope_sections, cfg.rope_theta)
             k = apply_mrope(k, positions3, cfg.mrope_sections, cfg.rope_theta)
-        elif positions is not None:
+        else:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
     scale = cfg.softmax_scale or (1.0 / math.sqrt(Dh))
-    new_cache = None
-    q_offset: jax.Array | int = 0
-    if kv_cache is not None:
+    if kv_cache is None:
+        out = sdpa(q, k, v, causal=cfg.causal and xattn_kv is None, window=cfg.window,
+                   scale=scale, is_global=is_global, impl=impl)
+        out = shard_hint(out.reshape(B, S, H * Dh), ("batch", "seq", "heads"))
+        return linear(p["wo"], out, dt), None
+    with jax.named_scope("kv_cache"):
+        # the step's cache traffic: write the new K/V, then read the whole
+        # cache in the two products over it
         ck, cv = kv_cache
-        assert cache_index is not None
         ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_index, axis=1)
         cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_index, axis=1)
-        new_cache = (ck, cv)
-        k, v = ck, cv
-        q_offset = cache_index
-        # mask out unfilled tail: positions beyond cache_index + S
-        out = _decode_sdpa(q, k, v, cfg, scale, q_offset, S)
-        out = out.reshape(B, S, H * Dh)
-        return linear(p["wo"], out, dt), new_cache
-    out = sdpa(
-        q, k, v,
-        causal=cfg.causal and xattn_kv is None,
-        window=cfg.window,
-        scale=scale,
-        impl=impl,
-    )
-    out = out.reshape(B, S, H * Dh)
-    out = shard_hint(out, ("batch", "seq", "heads"))
-    return linear(p["wo"], out, dt), new_cache
-
-
-def _decode_sdpa(q, k, v, cfg: AttnConfig, scale, q_offset, q_len) -> jax.Array:
-    """Decode attention over a (partially filled) cache: mask = causal wrt
-    q_offset and cache validity."""
-    B, Sq, H, Dh = q.shape
-    Skv = k.shape[1]
-    Hk = k.shape[2]
-    group = H // Hk
-    qf = q.astype(jnp.float32) * scale
-    qg = qf.reshape(B, Sq, Hk, group, Dh)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32))
-    qpos = jnp.arange(Sq)[:, None] + q_offset
-    kpos = jnp.arange(Skv)[None, :]
-    mask = kpos <= qpos
-    if cfg.window is not None:
-        mask &= kpos > qpos - cfg.window
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, Sq, H, Dh).astype(q.dtype)
+        # causal in the cache's timeline: positions past the fill are masked
+        out = sdpa(q, ck, cv, causal=True, window=cfg.window, scale=scale,
+                   q_offset=cache_index, is_global=is_global)
+    return linear(p["wo"], out.reshape(B, S, H * Dh), dt), (ck, cv)
 
 
 # ---------------------------------------------------------------------------

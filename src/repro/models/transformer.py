@@ -14,11 +14,16 @@ configs trace/compile in O(1) layers: one stack, or, with
 heterogeneity inside a stack (gemma's every-Nth-global pattern) rides
 along as a scanned boolean that switches the attention mask dynamically.
 
+The dense attention layer is ``common.attention`` (projections, qk-norm,
+RoPE or M-RoPE, ``common.sdpa``); ``cfg.attn_impl`` picks ``sdpa``'s jnp
+body or the flash kernel, and decoding attends over the cache through the
+same layer.
+
 Latent attention (``cfg.mla``, training and prefill only): q = h W_q as
 H x [nope | rope]; [c | k_rope] = h W_kv_a; c <- RMSNorm(c);
 [k_nope | v] = c W_kv_b as H x [nope | v]; RoPE (rotate-half layout) on
-q_rope and on the one k_rope head that all heads share; causal softmax of
-q.k / sqrt(nope + rope) against v.  The latent cache of decoding is not
+q_rope and on the one k_rope head that all heads share; ``common.sdpa``
+of q.k / sqrt(nope + rope) against v.  The latent cache of decoding is not
 implemented: ``init_cache`` and ``decode_step`` refuse such configs.
 
 API (used by train/serve/launch):
@@ -33,15 +38,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
-from ..parallel.sharding import _manual_axes_in_context, current_mesh, shard_hint
+from ..parallel.sharding import shard_hint
 from . import common as C
 from .common import DTypes, Params
 from .moe import MoEConfig, init_moe, moe_ffn, moe_specs
@@ -205,9 +208,9 @@ def _layer_fwd(
         if cfg.mla is not None:
             attn_out = _mla_attention(lp["attn"], cfg, h, positions, dt)
         else:
-            attn_out = _attention_dynwin(
-                lp["attn"], _attn_cfg(cfg), h, positions, positions3, is_global, dt,
-                cfg.attn_impl,
+            attn_out, _ = C.attention(
+                lp["attn"], _attn_cfg(cfg), h, positions, dt, positions3=positions3,
+                is_global=is_global, impl=cfg.attn_impl,
             )
         x = x + attn_out
     stats: Dict[str, jax.Array] = {}
@@ -244,193 +247,9 @@ def _mla_attention(p, cfg: ModelConfig, x, positions, dt):
     q = shard_hint(q, ("batch", "seq", "heads", "head_dim"))
     k = shard_hint(k, ("batch", "seq", "heads", "head_dim"))
     v = shard_hint(v, ("batch", "seq", "heads", "head_dim"))
-    scale = 1.0 / math.sqrt(dn + dr)
-    if cfg.attn_impl in ("flash", "flash_stub"):
-        out = _flash_attention(q, k, v, True, scale, stub=(cfg.attn_impl == "flash_stub"))
-    else:
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
-                            k.astype(jnp.float32))
-        mask = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-        probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1)
-        out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32)).astype(x.dtype)
+    out = C.sdpa(q, k, v, causal=True, window=None, scale=1.0 / math.sqrt(dn + dr),
+                 impl=cfg.attn_impl)
     out = shard_hint(out.reshape(B, S, H * dv), ("batch", "seq", "heads"))
-    return C.linear(p["wo"], out, dt)
-
-
-def _np_attention(q, k, v, causal, window, scale):
-    """Host numpy GQA attention — the pure_callback body of the flash stub
-    (semantically correct if executed; the dry-run only lowers it)."""
-    import numpy as np
-
-    q = np.asarray(q, np.float32)
-    k = np.asarray(k, np.float32)
-    v = np.asarray(v, np.float32)
-    B, S, H, Dh = q.shape
-    Hk = k.shape[2]
-    g = H // Hk
-    kr = np.repeat(k, g, axis=2)
-    vr = np.repeat(v, g, axis=2)
-    logits = np.einsum("bqhd,bkhd->bhqk", q * scale, kr)
-    qpos = np.arange(S)[:, None]
-    kpos = np.arange(S)[None, :]
-    mask = np.ones((S, S), bool)
-    if causal:
-        mask &= kpos <= qpos
-    if window is not None:
-        mask &= kpos > qpos - window
-    logits = np.where(mask[None, None], logits, -1e30)
-    p = np.exp(logits - logits.max(-1, keepdims=True))
-    p /= p.sum(-1, keepdims=True)
-    return np.einsum("bhqk,bkhd->bqhd", p, vr)
-
-
-def _stub_flash(q, k, v, causal, window, scale):
-    """Opaque fused-attention op: lowers to one custom-call whose HBM
-    traffic is exactly a flash kernel's (q,k,v in / o out; bwd likewise).
-    Used by the dry-run; execution falls back to the host numpy oracle."""
-
-    def fwd_cb(q, k, v):
-        return _np_attention(q, k, v, causal, window, scale).astype(q.dtype)
-
-    @jax.custom_vjp
-    def op(q, k, v):
-        return jax.pure_callback(
-            fwd_cb, jax.ShapeDtypeStruct(q.shape, q.dtype), q, k, v,
-            vmap_method="sequential",
-        )
-
-    def op_fwd(q, k, v):
-        return op(q, k, v), (q, k, v)
-
-    def op_bwd(res, do):
-        q, k, v = res
-
-        def bwd_cb(q, k, v, do):
-            import numpy as np
-
-            qj, kj, vj = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
-            _, vjp = jax.vjp(
-                lambda a, b, c: jnp.asarray(
-                    _np_attention(a, b, c, causal, window, scale)
-                ).astype(a.dtype),
-                qj, kj, vj,
-            )
-            dq, dk, dv = vjp(jnp.asarray(do))
-            return (np.asarray(dq), np.asarray(dk), np.asarray(dv))
-
-        dq, dk, dv = jax.pure_callback(
-            bwd_cb,
-            (
-                jax.ShapeDtypeStruct(q.shape, q.dtype),
-                jax.ShapeDtypeStruct(k.shape, k.dtype),
-                jax.ShapeDtypeStruct(v.shape, v.dtype),
-            ),
-            q, k, v, do,
-            vmap_method="sequential",
-        )
-        return dq, dk, dv
-
-    op.defvjp(op_fwd, op_bwd)
-    return op(q, k, v)
-
-
-def _flash_attention(q, k, v, causal, scale, stub=False):
-    """Flash attention in model layout, placed per shard so that scores
-    never materialize in HBM.
-
-    * No mesh in context: the kernel is called directly.
-    * Under a mesh: a shard_map island with batch over the DP axes and
-      heads over the TP axis.  Inside a manual region (the ``manual_hier``
-      trainer) the arrays are already per-shard on the manual axes, so the
-      island spans only the remaining auto axes, and the kernel is called
-      directly when none remain.
-
-    GQA KV heads are broadcast to the query heads before an island so the
-    head dim shards cleanly (the kernels reduce dk/dv back over the group).
-    ``stub=True`` lowers the kernel as an opaque custom-call (dry run: the
-    CPU backend cannot compile TPU Pallas; the stub carries identical
-    operand/result traffic)."""
-    from ..kernels.flash_attention.ops import flash_attention
-
-    def kernel(q, k, v):
-        if stub:
-            return _stub_flash(q, k, v, causal, None, scale)
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-
-    mesh = current_mesh()
-    manual = _manual_axes_in_context() or set()
-    auto = [a for a in mesh.axis_names if a not in manual] if mesh is not None else []
-    if not auto:
-        return kernel(q, k, v)
-    B, S, H, Dh = q.shape
-    group = H // k.shape[2]
-    if group > 1:
-        k = jnp.repeat(k, group, axis=2)
-        v = jnp.repeat(v, group, axis=2)
-    dp = tuple(a for a in ("pod", "data") if a in auto)
-    tp = "model" if "model" in auto else None
-    bspec = dp if (dp and B % math.prod(mesh.shape[a] for a in dp) == 0) else None
-    hspec = tp if (tp and H % mesh.shape[tp] == 0) else None
-    spec = P(bspec, None, hspec, None)
-    return jax.shard_map(
-        kernel, mesh=jax.sharding.get_abstract_mesh() if manual else mesh,
-        in_specs=(spec, spec, spec), out_specs=spec,
-        axis_names=set(auto), check_vma=False,
-    )(q, k, v)
-
-
-def _attention_dynwin(
-    p, acfg: C.AttnConfig, x, positions, positions3, is_global, dt, impl
-):
-    """Attention where the sliding window is switched per layer by a traced
-    boolean (gemma-style local:global inside one scan)."""
-    B, S, D = x.shape
-    H, Hk, Dh = acfg.heads, acfg.kv_heads, acfg.head_dim
-    q = C.linear(p["wq"], x, dt).reshape(B, S, H, Dh)
-    k = C.linear(p["wk"], x, dt).reshape(B, S, Hk, Dh)
-    v = C.linear(p["wv"], x, dt).reshape(B, S, Hk, Dh)
-    q = shard_hint(q, ("batch", "seq", "heads", "head_dim"))
-    k = shard_hint(k, ("batch", "seq", "kv_heads", "head_dim"))
-    v = shard_hint(v, ("batch", "seq", "kv_heads", "head_dim"))
-    if acfg.qk_norm:
-        q = C.rmsnorm(p["q_norm"], q)
-        k = C.rmsnorm(p["k_norm"], k)
-    if acfg.mrope_sections is not None and positions3 is not None:
-        q = C.apply_mrope(q, positions3, acfg.mrope_sections, acfg.rope_theta)
-        k = C.apply_mrope(k, positions3, acfg.mrope_sections, acfg.rope_theta)
-    else:
-        q = C.apply_rope(q, positions, acfg.rope_theta)
-        k = C.apply_rope(k, positions, acfg.rope_theta)
-    scale = 1.0 / math.sqrt(Dh)
-    if impl in ("flash", "flash_stub"):
-        if acfg.window is not None:
-            # the layer's window is switched by a traced flag, which the
-            # kernel's static mask cannot follow
-            raise ValueError(
-                f"attn_impl={impl!r} has no path for sliding-window layers "
-                f"(window={acfg.window}); use attn_impl='ref'"
-            )
-        out = _flash_attention(
-            q, k, v, acfg.causal, scale, stub=(impl == "flash_stub")
-        )
-        out = out.reshape(B, S, H * Dh)
-        out = shard_hint(out, ("batch", "seq", "heads"))
-        return C.linear(p["wo"], out, dt)
-    group = H // Hk
-    qf = q.astype(jnp.float32) * scale
-    qg = qf.reshape(B, S, Hk, group, Dh)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k.astype(jnp.float32))
-    qpos = jnp.arange(S)[:, None]
-    kpos = jnp.arange(S)[None, :]
-    mask = kpos <= qpos
-    if acfg.window is not None:
-        wmask = kpos > qpos - acfg.window
-        mask = mask & (wmask | is_global)
-    logits = jnp.where(mask[None, None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
-    out = out.reshape(B, S, H * Dh).astype(x.dtype)
-    out = shard_hint(out, ("batch", "seq", "heads"))
     return C.linear(p["wo"], out, dt)
 
 
@@ -574,9 +393,9 @@ def decode_step(
         lp, ck, cv, is_global = xs
         with jax.named_scope("attention"):
             h = C.rmsnorm(lp["ln1"], x, cfg.rms_norm_eps)
-            out, (nk, nv) = _decode_attention(
-                lp["attn"], acfg, cfg, h, positions, positions3, is_global,
-                (ck, cv), index, dt,
+            out, (nk, nv) = C.attention(
+                lp["attn"], acfg, h, positions, dt, kv_cache=(ck, cv), cache_index=index,
+                positions3=positions3, is_global=is_global,
             )
             x = x + out
         with jax.named_scope(_ffn_scope(lp)):
@@ -595,44 +414,3 @@ def decode_step(
     new_cache = {"k": nks, "v": nvs, "index": index + S}
     return _head(params, cfg, x, dt), new_cache
 
-
-def _decode_attention(
-    p, acfg: C.AttnConfig, cfg: ModelConfig, x, positions, positions3,
-    is_global, kv_cache, index, dt,
-):
-    B, S, D = x.shape
-    H, Hk, Dh = acfg.heads, acfg.kv_heads, acfg.head_dim
-    q = C.linear(p["wq"], x, dt).reshape(B, S, H, Dh)
-    k = C.linear(p["wk"], x, dt).reshape(B, S, Hk, Dh)
-    v = C.linear(p["wv"], x, dt).reshape(B, S, Hk, Dh)
-    if acfg.qk_norm:
-        q = C.rmsnorm(p["q_norm"], q)
-        k = C.rmsnorm(p["k_norm"], k)
-    if acfg.mrope_sections is not None and positions3 is not None:
-        q = C.apply_mrope(q, positions3, acfg.mrope_sections, acfg.rope_theta)
-        k = C.apply_mrope(k, positions3, acfg.mrope_sections, acfg.rope_theta)
-    else:
-        q = C.apply_rope(q, positions, acfg.rope_theta)
-        k = C.apply_rope(k, positions, acfg.rope_theta)
-    scale = 1.0 / math.sqrt(Dh)
-    group = H // Hk
-    with jax.named_scope("kv_cache"):
-        # the step's cache traffic: write the new K/V, then read the whole
-        # cache in the two products over it
-        ck, cv = kv_cache
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), index, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), index, axis=1)
-        Skv = ck.shape[1]
-        qf = q.astype(jnp.float32) * scale
-        qg = qf.reshape(B, S, Hk, group, Dh)
-        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck.astype(jnp.float32))
-        qpos = jnp.arange(S)[:, None] + index
-        kpos = jnp.arange(Skv)[None, :]
-        mask = kpos <= qpos
-        if acfg.window is not None:
-            mask = mask & ((kpos > qpos - acfg.window) | is_global)
-        logits = jnp.where(mask[None, None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, cv.astype(jnp.float32))
-    out = out.reshape(B, S, H * Dh).astype(x.dtype)
-    return C.linear(p["wo"], out, dt), (ck, cv)
