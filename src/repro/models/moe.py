@@ -7,8 +7,12 @@ Three implementations:
   token dropped: the router scores every expert, the (token, expert)
   pairs routed to the experts this chip holds are sorted by expert and
   go through grouped matrix products over the held experts (Pallas
-  megablox ``gmm`` on the TPU, ``lax.ragged_dot`` elsewhere).  Taken
-  whenever the configuration names the held experts
+  megablox ``gmm`` on the TPU, ``lax.ragged_dot`` elsewhere).  The pair
+  buffer keeps its worst-case size (T x top_k rows), but on the TPU no
+  work touches a row past the held pairs: the row gather and the gated
+  combine are Pallas kernels that stop at their count
+  (``kernels/moe_dispatch``), and the products write only the held
+  groups.  Taken whenever the configuration names the held experts
   (``MoEConfig.held_experts``); on one chip it runs without the exchange
   that would bring the other chips' tokens.
 
@@ -32,13 +36,13 @@ the held path has none.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..kernels.moe_dispatch import ops as dispatch_ops
 from ..parallel.sharding import current_mesh, shard_hint
 from .common import DTypes, Params, init_linear, linear_specs, trunc_normal
 
@@ -306,46 +310,6 @@ def route_topk(router_w: jax.Array, cfg: MoEConfig, xt: jax.Array):
     return gates * cfg.routed_scale, idx, scores
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inv, k):
-    """Rows of x (T, D) for the (token, expert) pairs in ``order`` (T*k,),
-    pair p being token p // k.  The gradient gathers too: each token sums
-    its k pairs' rows (``inv`` is the inverse permutation)."""
-    return x[order // k]
-
-
-def _dispatch_fwd(x, order, inv, k):
-    return x[order // k], (order, inv)
-
-
-def _dispatch_bwd(k, res, g):
-    order, inv = res
-    gt = g[inv].astype(jnp.float32)
-    return gt.reshape(-1, k, g.shape[-1]).sum(axis=1).astype(g.dtype), None, None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def _permute(y, idx, inv):
-    """y[idx] for a permutation ``idx`` whose inverse is ``inv``; its
-    gradient is the gather g[inv], not a scatter."""
-    return y[idx]
-
-
-def _permute_fwd(y, idx, inv):
-    return y[idx], (idx, inv)
-
-
-def _permute_bwd(res, g):
-    _, inv = res
-    return g[inv], None, None
-
-
-_permute.defvjp(_permute_fwd, _permute_bwd)
-
-
 def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
     """megablox tiles (tm, tk, tn), for ``gmm`` (tm x tk by tk x tn into a
     tm x tn accumulator) and ``tgmm`` (tk x tm by tm x tn into tk x tn):
@@ -374,14 +338,15 @@ def _on_cpu() -> bool:
 
 
 def _grouped(x: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
-    """Row group g of x (sizes[g] consecutive rows, g < len(w)) times
-    w[g]; rows past the groups (``sizes`` has one more entry, for them)
-    come out zero."""
+    """Row group g of x (sizes[g] consecutive rows, one group for each
+    w[g]) times w[g].  Rows past the groups are not written on the TPU
+    (megablox leaves them as the buffer held, and its backward reads
+    none of them) and come out zero elsewhere."""
     if not _on_cpu():
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        return gmm(x, w, sizes, x.dtype, _gmm_tiling)
-    return jax.lax.ragged_dot(x, w, sizes[:-1]).astype(x.dtype)
+        return gmm(x, w, sizes, x.dtype, _gmm_tiling, interpret=dispatch_ops._on_cpu())
+    return jax.lax.ragged_dot(x, w, sizes).astype(x.dtype)
 
 
 def moe_ffn_held(
@@ -402,21 +367,23 @@ def moe_ffn_held(
         group = jnp.where((local >= 0) & (local < Eh), local, Eh)   # Eh: not held
         order = jnp.argsort(group, stable=True)
         inv = jnp.argsort(order)
-        sizes = jnp.bincount(group, length=Eh + 1).astype(jnp.int32)
-        held = T * K - sizes[Eh]
+        sizes = jnp.bincount(group, length=Eh + 1).astype(jnp.int32)[:Eh]
+        held = jnp.sum(sizes)
     with jax.named_scope("moe_experts"):
-        # pairs of experts not held sort last; their rows come out zero
-        xs = _dispatch(xt, order, inv, K)
+        # pairs of experts not held sort last: rows past ``held`` are not
+        # read, and on the TPU not written either
+        impl = "ref" if _on_cpu() else "kernel"
+        pos = inv.reshape(T, K)
+        xs = dispatch_ops.dispatch(xt, order, pos, sizes, impl)
         h = jax.nn.silu(_grouped(xs, dt.c(p["wg"]), sizes)) * _grouped(xs, dt.c(p["wi"]), sizes)
         y = _grouped(h, dt.c(p["wo"]), sizes)
-        y = _permute(y, inv, order).reshape(T, K, D).astype(jnp.float32)
-        out = jnp.sum(y * gates[..., None], axis=1).astype(x.dtype).reshape(B, S, D)
+        out = dispatch_ops.combine(y, gates, order, pos, sizes, impl).reshape(B, S, D)
     if cfg.num_shared_experts:
         from .common import swiglu
 
         with jax.named_scope("moe_shared"):
             out = out + swiglu(p["shared"], x, dt)
-    load = sizes[:Eh].astype(jnp.float32)
+    load = sizes.astype(jnp.float32)
     mean = jnp.mean(load)
     stats = {"moe_tokens_held": held,
              "moe_max_load": jnp.where(mean > 0, jnp.max(load) / jnp.maximum(mean, 1e-9), 0.0)}

@@ -9,6 +9,7 @@ only the test worker that is handed this file loads the TPU library.
 """
 
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -264,7 +265,7 @@ TINY_MLA = dict(name="tiny-mla", family="moe", num_layers=2, d_model=256, heads=
                 first_dense_layers=1,
                 mla={"kv_lora_rank": 128, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
                      "v_head_dim": 128},
-                moe={"num_experts": 8, "top_k": 2, "d_ff": 256, "num_shared_experts": 1,
+                moe={"num_experts": 8, "top_k": 2, "d_ff": 128, "num_shared_experts": 1,
                      "scoring": "sigmoid", "routed_scale": 2.446, "held_experts": 4,
                      "aux_loss_coeff": 0.0},
                 param_dtype=jnp.float32, compute_dtype=jnp.bfloat16, remat=True, attn_impl="flash")
@@ -276,6 +277,7 @@ def compiled_moe_step(topo, no_persistent_cache):
     model's train step for one described chip, kernels compiled."""
     from repro.configs.base import ModelConfig
     from repro.kernels.flash_attention import ops
+    from repro.kernels.moe_dispatch import ops as dispatch_ops
     from repro.models import moe
     from repro.models.model_zoo import get_model
     from repro.train import optimizer as opt_lib
@@ -289,6 +291,7 @@ def compiled_moe_step(topo, no_persistent_cache):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_on_cpu", lambda: False)
         mp.setattr(moe, "_on_cpu", lambda: False)
+        mp.setattr(dispatch_ops, "_on_cpu", lambda: False)
         arts = make_train_step(zoo, ocfg, mesh, example)
         batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=arts.batch_sharding[k])
                  for k, v in example.items()}
@@ -303,16 +306,24 @@ def _sub_scopes(op_name: str) -> set:
 
 
 def test_moe_step_kernels_and_sub_scopes(compiled_moe_step):
-    """The flash kernels sit under ``attention`` and the grouped products
-    under ``moe``/``moe_experts``; the step has the sub-scopes the
-    benchmark's readers take (``moe_route``, ``moe_experts``,
-    ``moe_shared``, ``mla_kv``), and they count under their scope."""
-    kernels, subs = set(), {}
+    """The flash kernels sit under ``attention``; the grouped products and
+    the pair gather and combine under ``moe``/``moe_experts``, forward and
+    backward, so ``expert_roofline_pct.train`` counts their time; the step
+    has the sub-scopes the benchmark's readers take (``moe_route``,
+    ``moe_experts``, ``moe_shared``, ``mla_kv``), and they count under
+    their scope.  No op under ``moe_experts`` writes an f32 buffer of every
+    (token, expert) pair, (T*K, D) or (T, K, D)."""
+    T, K, D = TB * TS, TINY_MLA["moe"]["top_k"], TINY_MLA["d_model"]
+    full_f32 = {f"f32[{T * K},{D}]", f"f32[{T},{K},{D}]"}
+    kernels, subs, forward, backward = set(), {}, set(), set()
     for ins in scopes.instructions(compiled_moe_step):
         parts = _sub_scopes(ins.op_name)
         for sub in ("moe_route", "moe_experts", "moe_shared", "mla_kv"):
             if sub in parts:
                 subs.setdefault(sub, set()).add(scopes.scope_of(ins.op_name))
+        if "moe_experts" in parts:
+            shapes = set(re.findall(r"f32\[[\d,]*\]", ins.shape))
+            assert not shapes & full_f32, ins.text[:300]
         if 'custom_call_target="tpu_custom_call"' not in ins.text:
             continue
         name = scopes.kernel_of(ins.op_name)
@@ -321,6 +332,10 @@ def test_moe_step_kernels_and_sub_scopes(compiled_moe_step):
         assert scopes.scope_of(ins.op_name) == want, ins.op_name
         if want == "moe":
             assert "moe_experts" in parts, ins.op_name
-    assert {"flash_fwd_lse", "flash_bwd_dq", "flash_bwd_dkv", "gmm", "tgmm"} <= kernels
+            (backward if "/transpose(" in ins.op_name else forward).add(name)
+    assert {"flash_fwd_lse", "flash_bwd_dq", "flash_bwd_dkv", "gmm", "tgmm",
+            "moe_gather", "moe_combine"} <= kernels
+    assert {"gmm", "moe_gather", "moe_combine"} <= forward
+    assert {"gmm", "tgmm", "moe_gather", "moe_combine"} <= backward
     assert subs == {"moe_route": {"moe"}, "moe_experts": {"moe"}, "moe_shared": {"moe"},
                     "mla_kv": {"attention"}}
